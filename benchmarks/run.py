@@ -12,6 +12,9 @@ from benchmarks import common
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_autoscale,
         bench_fleet,

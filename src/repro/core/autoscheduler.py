@@ -27,9 +27,12 @@ import numpy as np
 
 from repro.core.cost_model import Measurement
 from repro.core.database import Record, ScheduleDB
+from repro.core.legality import axis_units
 from repro.core.runner import MeasureRunner, resolve_runner, telemetry_delta
+from repro.hw.specs import TPU_V5E
 from repro.targets import DEFAULT_TARGET
 from repro.core.schedule import (
+    REDUCTION_AXIS,
     UNROLL_CHOICES,
     VEC_CHOICES,
     Schedule,
@@ -47,27 +50,28 @@ TILE_POOL = tuple(sorted(
 ))
 
 
-def _divisor_tiles(extent: int) -> list[int]:
-    """Candidate tile sizes for an extent: divisors near hardware-friendly sizes."""
-    out = sorted({d for d in TILE_POOL if d <= extent and extent % d == 0})
-    if not out:
-        out = [1]
-    if extent <= 2048 and extent not in out:
+def _divisor_tiles(extent: int, unit: int) -> list[int]:
+    """Candidate tile sizes for an extent: divisors near hardware-friendly
+    sizes that are multiples of ``unit`` (the axis's block alignment, see
+    :mod:`repro.core.legality`), plus the always-legal full extent."""
+    out = sorted({d for d in TILE_POOL
+                  if d <= extent and extent % d == 0 and d % unit == 0})
+    if (extent <= 2048 or not out) and extent not in out:
         out.append(extent)
     return out
 
 
 def random_schedule(instance: KernelInstance, rng: random.Random) -> Schedule:
     axes = class_axes(instance.class_id)
-    tiles = {a: rng.choice(_divisor_tiles(instance.extent(a))) for a in axes}
-    reduction = {"matmul": "K", "attention": "KV", "scan": "T"}[instance.family]
-    non_reduction = [a for a in axes if a != reduction]
-    rng.shuffle(non_reduction)
-    # Reduction axis position: anywhere but first (keeps ≥1 parallelizable axis).
-    pos = rng.randrange(1, len(axes))
-    order = non_reduction[:]
-    order.insert(pos, reduction)
-    parallel = rng.randint(1, max(1, order.index(reduction)))
+    units = axis_units(instance, TPU_V5E)
+    tiles = {a: rng.choice(_divisor_tiles(instance.extent(a), units.get(a, 1)))
+             for a in axes}
+    reduction = REDUCTION_AXIS[instance.family]
+    order = [a for a in axes if a != reduction]
+    rng.shuffle(order)
+    # The reduction axis runs innermost: the only order the kernels realize.
+    order.append(reduction)
+    parallel = rng.randint(1, max(1, len(order) - 1))
     return Schedule.make(
         instance.class_id,
         tiles=tiles,
@@ -88,15 +92,18 @@ def mutate(schedule: Schedule, instance: KernelInstance, rng: random.Random) -> 
     parallel, unroll, vec, cache = schedule.parallel, schedule.unroll, schedule.vec, schedule.cache_write
     if kind == "tile":
         a = rng.choice(axes)
-        choices = _divisor_tiles(instance.extent(a))
+        choices = _divisor_tiles(instance.extent(a),
+                                 axis_units(instance, TPU_V5E).get(a, 1))
         tiles[a] = rng.choice(choices)
     elif kind == "order":
-        reduction = {"matmul": "K", "attention": "KV", "scan": "T"}[instance.family]
-        i, j = rng.sample(range(len(order)), 2) if len(order) >= 2 else (0, 0)
-        order[i], order[j] = order[j], order[i]
-        if order[0] == reduction:  # keep one leading parallelizable axis
-            order[0], order[1] = order[1], order[0]
-        parallel = min(parallel, max(1, order.index(reduction)))
+        # permute the outer axes; the reduction axis stays innermost
+        reduction = REDUCTION_AXIS[instance.family]
+        order = [a for a in order if a != reduction]
+        if len(order) >= 2:
+            i, j = rng.sample(range(len(order)), 2)
+            order[i], order[j] = order[j], order[i]
+        order.append(reduction)
+        parallel = min(parallel, max(1, len(order) - 1))
     elif kind == "unroll":
         unroll = rng.choice(UNROLL_CHOICES)
     elif kind == "vec":

@@ -10,7 +10,8 @@ overhead rather than TPU behaviour.  Instead we model, per kernel family:
 * a memory term — HBM traffic **derived from the tiling and grid order**,
   using Pallas' consecutive-revisit semantics (a block is re-fetched unless
   its index map is unchanged between consecutive grid steps);
-* VMEM capacity validity (double-buffered operand blocks + accumulators);
+* legality (:mod:`repro.core.legality`): block alignment and the VMEM
+  footprint Mosaic allocates, against the target's budget;
 * pipeline fill/launch overheads and an unroll instruction-overhead knob.
 
 Time = max(compute, memory) + overheads, then a seeded log-normal noise
@@ -30,7 +31,9 @@ import math
 import struct
 from typing import Mapping, Sequence
 
-from repro.core.schedule import ConcreteSchedule, Schedule, ScheduleInvalid, concretize, default_schedule
+from repro.core.legality import check as check_legal, vmem_bytes
+from repro.core.schedule import (REDUCTION_AXIS, ConcreteSchedule, Schedule, ScheduleInvalid,
+                                 concretize, default_schedule)
 from repro.core.workload import KernelInstance, KernelUse, class_family
 from repro.hw.specs import TPU_V5E, ChipSpec, dim_efficiency
 
@@ -88,11 +91,12 @@ def _operand_fetches(order: Sequence[str], trips: Mapping[str, int], dep: set[st
     axes `dep`, under Pallas consecutive-revisit caching.
 
     The block stays VMEM-resident across the innermost contiguous run of grid
-    axes it does NOT depend on; every other step boundary re-fetches it.
+    axes it does NOT depend on (an axis of one trip never changes an index);
+    every other step boundary re-fetches it.
     """
     run = 1
     for axis in reversed(order):
-        if axis in dep:
+        if axis in dep and trips[axis] > 1:
             break
         run *= trips[axis]
     total = math.prod(trips[a] for a in order)
@@ -146,28 +150,17 @@ def _matmul_cost(cs: ConcreteSchedule, spec: ChipSpec) -> CostBreakdown:
     vpu_flops = spec.peak_flops_bf16 / 16.0
     compute_s = flops / (spec.peak_flops_bf16 * max(mxu_eff, 1e-3)) + epi / vpu_flops
 
-    # --- memory term (order-dependent HBM traffic) ---
+    # --- memory term (order-dependent HBM traffic; K innermost, so each
+    # output block is written once) ---
     fetches_a = _operand_fetches(order, trips, {"M", "K"})
     fetches_b = _operand_fetches(order, trips, {"K", "N"})
     bytes_a = fetches_a * bm * bk * es
     bytes_b = fetches_b * bk * bn * es
-    out_tiles = trips["M"] * trips["N"]
-    if _acc_resident(order):
-        bytes_c = out_tiles * bm * bn * es  # written once
-    else:
-        # accumulator revisited non-consecutively: spill+reload per K segment
-        fetches_c = _operand_fetches(order, trips, {"M", "N"})
-        bytes_c = 2 * fetches_c * bm * bn * es
+    bytes_c = trips["M"] * trips["N"] * bm * bn * es
     hbm = (bytes_a + bytes_b + bytes_c) * E
     if E > 1:
         hbm += 2.0 * M * K * es  # token dispatch + combine
     memory_s = hbm / spec.hbm_bandwidth
-
-    # --- VMEM validity ---
-    acc_bytes = bm * bn * (4 if sched.cache_write else es)
-    vmem = 2 * (bm * bk + bk * bn) * es + acc_bytes + bm * bn * es
-    if vmem > spec.vmem_capacity:
-        raise ScheduleInvalid(f"VMEM overflow: {vmem} > {spec.vmem_capacity}")
 
     # --- overheads ---
     steps = math.prod(trips.values()) * E
@@ -182,13 +175,8 @@ def _matmul_cost(cs: ConcreteSchedule, spec: ChipSpec) -> CostBreakdown:
         overhead_s=overhead + (base - max(compute_s * icache_penalty, memory_s)),
         flops=flops + epi,
         hbm_bytes=hbm,
-        vmem_bytes=vmem,
+        vmem_bytes=vmem_bytes(cs, spec),
     )
-
-
-def _acc_resident(order: Sequence[str]) -> bool:
-    """Output accumulator stays VMEM-resident iff K is the innermost axis."""
-    return order[-1] == "K"
 
 
 # ---------------------------------------------------------------------------
@@ -229,20 +217,9 @@ def _attention_cost(cs: ConcreteSchedule, spec: ChipSpec) -> CostBreakdown:
 
     trips_q = max(1, math.ceil(Q / bq))
     trips_kv = max(1, math.ceil(KV / bkv))
-    q_outer = cs.order[0] == "Q"
-    if q_outer:
-        # stream K/V per q block (classic flash): K/V re-read per q tile
-        bytes_ = B * H * (Q * D * es + 2 * KV * D * es * trips_q * frac + Q * D * es)
-    else:
-        # kv outer: q re-read per kv tile + softmax stats/acc spill per kv tile
-        bytes_ = B * H * (Q * D * es * trips_kv + 2 * KV * D * es * frac
-                          + 2 * Q * D * 4 * trips_kv + Q * D * es)
+    # KV innermost (classic flash): K/V stream once per q tile
+    bytes_ = B * H * (Q * D * es + 2 * KV * D * es * trips_q * frac + Q * D * es)
     memory_s = bytes_ / spec.hbm_bandwidth
-
-    acc_bytes = bq * D * (4 if sched.cache_write else es) + bq * 8  # acc + m/l stats
-    vmem = 2 * (bq * D + 2 * bkv * D) * es + bq * bkv * es + acc_bytes
-    if vmem > spec.vmem_capacity:
-        raise ScheduleInvalid(f"VMEM overflow: {vmem} > {spec.vmem_capacity}")
 
     steps = B * H * trips_q * trips_kv
     step_overhead = 80e-9 / (1.0 + sched.unroll / 8.0)
@@ -255,7 +232,7 @@ def _attention_cost(cs: ConcreteSchedule, spec: ChipSpec) -> CostBreakdown:
         overhead_s=overhead + base * fill,
         flops=flops + vpu,
         hbm_bytes=bytes_,
-        vmem_bytes=vmem,
+        vmem_bytes=vmem_bytes(cs, spec),
     )
 
 
@@ -289,10 +266,6 @@ def _scan_cost(cs: ConcreteSchedule, spec: ChipSpec) -> CostBreakdown:
     bytes_ = B * T * C * es * io_streams + B * T * C * es + 2 * state_bytes
     memory_s = bytes_ / spec.hbm_bandwidth
 
-    vmem = 2 * ct * bc * es * io_streams + bc * D * 4 + ct * bc * es
-    if vmem > spec.vmem_capacity:
-        raise ScheduleInvalid(f"VMEM overflow: {vmem} > {spec.vmem_capacity}")
-
     chunks = max(1, math.ceil(T / ct)) * max(1, math.ceil(C / bc)) * B
     step_overhead = 120e-9 / (1.0 + sched.unroll / 8.0)
     fill = 2.0 / max(chunks, 2)
@@ -304,7 +277,7 @@ def _scan_cost(cs: ConcreteSchedule, spec: ChipSpec) -> CostBreakdown:
         overhead_s=overhead + base * fill,
         flops=flops,
         hbm_bytes=bytes_,
-        vmem_bytes=vmem,
+        vmem_bytes=vmem_bytes(cs, spec),
     )
 
 
@@ -314,13 +287,14 @@ _FAMILY_COST = {"matmul": _matmul_cost, "attention": _attention_cost, "scan": _s
 def evaluate(cs: ConcreteSchedule, spec: ChipSpec = TPU_V5E) -> CostBreakdown:
     """Deterministic cost of a concrete (instance, schedule) binding.
 
-    Raises ScheduleInvalid on structural violations (VMEM overflow,
-    parallelized reduction axis).
+    Raises ScheduleInvalid on structural violations (reduction axis not
+    innermost or marked parallel, blocks Mosaic refuses, VMEM overflow).
     """
     sched = cs.schedule
-    reduction = {"matmul": "K", "attention": "KV", "scan": "T"}[cs.instance.family]
+    reduction = REDUCTION_AXIS[cs.instance.family]
     if reduction in sched.order[: sched.parallel]:
         raise ScheduleInvalid(f"reduction axis {reduction} marked parallel")
+    check_legal(cs, spec)
     return _FAMILY_COST[cs.instance.family](cs, spec)
 
 

@@ -38,6 +38,7 @@ import dataclasses
 import threading
 from typing import Iterable, Mapping, Sequence
 
+from repro.core.legality import check as check_legal
 from repro.core.schedule import (
     ConcreteSchedule,
     Schedule,
@@ -47,7 +48,7 @@ from repro.core.schedule import (
 )
 from repro.core.workload import KernelInstance, KernelUse, dedup_uses
 from repro.obs import NULL_TRACER, MetricsRegistry
-from repro.targets import DEFAULT_TARGET, target_name
+from repro.targets import DEFAULT_TARGET, resolve_target, target_name
 
 #: Resolution tiers, strongest first.  ``exact``/``transfer`` come from the
 #: online service, ``static`` from a frozen offline schedule map, ``default``
@@ -189,6 +190,9 @@ class ResolutionPipeline:
         self.stages = list(stages)
         self.mode = mode
         self.target = target_name(target) if target is not None else DEFAULT_TARGET
+        # Every answer passes the TPU block rule for this chip before it is
+        # served: a stage's schedule Mosaic would refuse falls through.
+        self.spec = resolve_target(self.target).spec
         self._lock = threading.Lock()
         self._cache: dict[tuple[str, str, str, int], Resolution] = {}
         # Per-stage generation vector: each stage's changed_since must be
@@ -203,7 +207,8 @@ class ResolutionPipeline:
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self._counters = self.metrics.group("resolution", [
             "resolves", "cache_hits", "cache_misses", "stage_calls",
-            "migrated", "invalidations", *(f"served_{t}" for t in TIERS)])
+            "migrated", "invalidations", "rejected_illegal",
+            *(f"served_{t}" for t in TIERS)])
 
     @staticmethod
     def build(schedule_map: Mapping[str, Schedule] | None = None,
@@ -256,12 +261,18 @@ class ResolutionPipeline:
             return res
 
         res = None
-        walked = 0
+        walked = rejected = 0
         for stage in self.stages:
             walked += 1
             res = stage.resolve(instance, mode)
-            if res is not None:
+            if res is None:
+                continue
+            try:
+                check_legal(res.concrete, self.spec)
                 break
+            except ScheduleInvalid:
+                rejected += 1
+                res = None
         if res is None:  # no terminal stage configured: untuned fallback
             res = Resolution(concretize(default_schedule(instance), instance),
                              "default", "fallback", "")
@@ -270,6 +281,7 @@ class ResolutionPipeline:
             self._counters["resolves"] += 1
             self._counters["cache_misses"] += 1
             self._counters["stage_calls"] += walked
+            self._counters["rejected_illegal"] += rejected
             self._counters[f"served_{res.tier}"] += 1
             self._cache[key] = res
         # Only stage walks are traced: memoized hits are the hot path and

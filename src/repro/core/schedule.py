@@ -167,9 +167,19 @@ def nearest_divisor(n: int, target: int) -> int:
 #: invalid transferred code, the analogue of the paper's Fig. 4 "-1" bars.
 MASKABLE_AXES = {"M", "N", "Q", "KV", "C"}
 
-#: GLU epilogues pair adjacent (gate, up) columns: a partial N tile is fine
-#: but an odd tile would split pairs.
+#: GLU epilogues pair (gate, up) column chunks: a partial N tile is fine but
+#: a tile that is not a whole number of pairs would split one.
 GLU_CLASSES = ("matmul_silu_glu", "matmul_gelu_glu", "moe_gemm_silu_glu")
+
+#: GLU weights pack gate and up columns in alternating chunks of this many
+#: columns (one vreg's lanes), so the kernel splits a block with lane-aligned
+#: slices; a width that is not a multiple of it packs as [gate | up].
+GLU_LANES = 128
+
+
+def glu_chunk(f: int) -> int:
+    """Chunk width of the (gate, up) packing for a GLU of ``f`` outputs."""
+    return GLU_LANES if f % GLU_LANES == 0 else f
 
 
 def concretize(schedule: Schedule, instance: KernelInstance, mode: str = "strict") -> ConcreteSchedule:
@@ -206,10 +216,12 @@ def concretize(schedule: Schedule, instance: KernelInstance, mode: str = "strict
             if mode == "strict":
                 raise ScheduleInvalid(f"tile {axis}={tile} does not divide extent {extent}")
             tile, adapted = nearest_divisor(extent, tile), True
-        if axis == "N" and instance.class_id in GLU_CLASSES and tile % 2:
-            if mode == "strict":
-                raise ScheduleInvalid(f"odd N tile {tile} splits GLU pairs")
-            tile, adapted = max(tile - 1, 2), True
+        if axis == "N" and instance.class_id in GLU_CLASSES:
+            pair = 2 * glu_chunk(extent // 2)
+            if tile != extent and tile % pair:
+                if mode == "strict":
+                    raise ScheduleInvalid(f"N tile {tile} splits GLU pairs of {pair}")
+                tile, adapted = min(max(pair, tile - tile % pair), extent), True
         tiles[axis] = tile
 
     grid = tuple(
@@ -248,14 +260,31 @@ _DEFAULT_TARGET = {"M": 128, "Q": 128, "T": 128, "N": 512, "KV": 512, "C": 512,
                    "K": 256, "E": 1}
 
 
+def _default_tile(extent: int, target: int, unit: int, maskable: bool) -> int:
+    """Largest divisor of ``extent`` that is <= ``target`` and a multiple of
+    ``unit`` (the block alignment); the full extent when it fits the target
+    or nothing aligned divides it, an aligned partial tile on maskable axes."""
+    if extent <= target:
+        return extent
+    aligned = [d for d in divisors_leq(extent, target) if d % unit == 0]
+    if aligned:
+        return aligned[-1]
+    if maskable and target >= unit:
+        return target - target % unit
+    return extent
+
+
 def default_schedule(instance: KernelInstance) -> Schedule:
+    from repro.core.legality import axis_units
     from repro.core.workload import class_family
+    from repro.hw.specs import TPU_V5E
 
     axes = class_axes(instance.class_id)
+    units = axis_units(instance, TPU_V5E)
     tiles: dict[str, int] = {}
     for axis in axes:
-        extent = instance.extent(axis)
-        tiles[axis] = nearest_divisor(extent, min(_DEFAULT_TARGET[axis], extent))
+        tiles[axis] = _default_tile(instance.extent(axis), _DEFAULT_TARGET[axis],
+                                    units.get(axis, 1), axis in MASKABLE_AXES)
     red = REDUCTION_AXIS[class_family(instance.class_id)]
     order = tuple(a for a in axes if a != red) + (red,)
     return Schedule.make(

@@ -24,12 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-if hasattr(jax, "shard_map"):                     # jax >= 0.6
-    _shard_map = functools.partial(jax.shard_map, check_vma=False)
-else:                                             # jax 0.4.x: experimental home
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    _shard_map = functools.partial(_experimental_shard_map, check_rep=False)
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 def bubble_fraction(n_stages: int, n_micro: int) -> float:

@@ -1,8 +1,9 @@
 """Hardware constants for the supported target platforms and roofline helpers.
 
-This container is CPU-only; the TPU chips are *targets*.  Every performance
-number in the framework (cost model, roofline terms) is derived from these
-constants, so they live in exactly one place.  Named specs are registered as
+Every cost-model number (and roofline term) is derived from these constants,
+so they live in exactly one place; ``vmem_capacity`` is also the VMEM limit
+every Pallas kernel hands the TPU compiler, and the budget the legality rule
+(:mod:`repro.core.legality`) sizes blocks against.  Named specs are registered as
 :class:`repro.targets.Target` entries — resolve them by name through
 ``repro.targets.get_target`` rather than importing constants directly.
 

@@ -11,12 +11,9 @@ tunes for the ``flash_attention_*`` kernel classes.  Supports:
 * decode (Sq=1 with a long KV context) — same kernel, bq clamps to Sq.
 
 Grid: (batch·q_heads, Q/bq, KV/bkv) with KV innermost so the f32 softmax
-state (m, l, acc scratch) persists across the KV trip.  The ``order`` field
-of attention schedules chooses whether Q or KV is the *outer* streaming
-axis in the cost model; the builder canonicalizes execution to KV-inner
-(see DESIGN.md — on TPU the accumulator state must live in VMEM across the
-reduction, so KV-outer realizations are strictly dominated and the cost
-model penalizes them with spill traffic).
+state (m, l, acc scratch) persists across the KV trip.  It is the only
+order the kernel realizes, so the legality rule (:mod:`repro.core.legality`)
+refuses attention schedules whose ``order`` puts KV outside Q.
 
 Validated against ref.attention / ref.chunked_attention in interpret mode.
 """
@@ -30,16 +27,18 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.schedule import ConcreteSchedule
+from repro.hw.specs import TPU_V5E
 
 NEG_INF = -1e30
 
 
-def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
+def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             kv_trips: int, bq: int, bkv: int, sq: int, skv: int,
             causal: bool, window: int, softcap: float, scale: float,
-            q_offset: int, out_dtype):
+            out_dtype):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    q_offset = off_ref[0]
 
     @pl.when(ki == 0)
     def _():
@@ -90,9 +89,16 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     cs: ConcreteSchedule, *, causal: bool = True,
-                    window: int = 0, softcap: float = 0.0, q_offset: int = 0,
-                    scale: float | None = None, interpret: bool = True) -> jax.Array:
-    """q: (B, Hq, Sq, D); k/v: (B, Hkv, Skv, D). Returns (B, Hq, Sq, D)."""
+                    window: int = 0, softcap: float = 0.0,
+                    q_offset: int | jax.Array = 0, scale: float | None = None,
+                    interpret: bool,
+                    vmem_limit_bytes: int = TPU_V5E.vmem_capacity
+                    ) -> jax.Array:
+    """q: (B, Hq, Sq, D); k/v: (B, Hkv, Skv, D). Returns (B, Hq, Sq, D).
+
+    ``q_offset`` (absolute position of query row 0) may be traced: it reaches
+    the kernel as a scalar-prefetch (SMEM) operand, so one compiled kernel
+    serves every chunk offset."""
     b, hq, sq, d = q.shape
     hkv, skv = k.shape[1], k.shape[2]
     group = hq // hkv
@@ -110,30 +116,35 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         # program id over b*hq -> row index into (b*hkv) k/v arrays
         return (bh // hq) * hkv + (bh % hq) // group
 
+    # Index maps take the scalar-prefetch ref as a trailing argument.
     in_specs = [
-        pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-        pl.BlockSpec((1, bkv, d), lambda bh, qi, ki: (kv_head(bh), ki, 0)),
-        pl.BlockSpec((1, bkv, d), lambda bh, qi, ki: (kv_head(bh), ki, 0)),
+        pl.BlockSpec((1, bq, d), lambda bh, qi, ki, off: (bh, qi, 0)),
+        pl.BlockSpec((1, bkv, d), lambda bh, qi, ki, off: (kv_head(bh), ki, 0)),
+        pl.BlockSpec((1, bkv, d), lambda bh, qi, ki, off: (kv_head(bh), ki, 0)),
     ]
-    out_specs = pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0))
+    out_specs = pl.BlockSpec((1, bq, d), lambda bh, qi, ki, off: (bh, qi, 0))
 
     kernel = functools.partial(
         _kernel,
         kv_trips=grid[2], bq=bq, bkv=bkv, sq=sq, skv=skv,
         causal=causal, window=window, softcap=softcap, scale=scale,
-        q_offset=q_offset, out_dtype=q.dtype,
+        out_dtype=q.dtype,
     )
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((bq, d), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((b * hq, sq, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-        ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
-    )(qf, kf, vf)
+    )(jnp.reshape(jnp.asarray(q_offset, jnp.int32), (1,)), qf, kf, vf)
     return out.reshape(b, hq, sq, d)

@@ -3,21 +3,22 @@
 The kernel realizes a :class:`repro.core.schedule.ConcreteSchedule` on TPU:
 
 * ``tiles``      → BlockSpec block shapes (bm, bn, bk);
-* ``order``      → grid axis order (Pallas iterates the last grid dim
-                    fastest, i.e. ``order[-1]`` is the innermost loop);
-* ``cache_write``→ f32 VMEM scratch accumulator (requires the reduction axis
-                    K innermost so the scratch survives the whole K trip);
-                    otherwise partial sums are accumulated into the output
-                    block (read-modify-write on revisits — the spill traffic
-                    the cost model charges for non-K-inner orders);
-* ``parallel``   → ``dimension_semantics`` prefix (TPU compiler hint);
+* ``order``      → grid order of the M and N axes (Pallas iterates the last
+                    grid dim fastest).  The reduction axis K always runs
+                    innermost: Pallas on TPU writes an output block back when
+                    its index changes and never reads it in again, so an
+                    accumulator revisited across a K-outer loop would restart
+                    from stale VMEM.  The legality rule refuses K-outer
+                    orders (:mod:`repro.core.legality`);
+* ``cache_write``→ f32 VMEM scratch accumulator; otherwise partial sums are
+                    accumulated in the output block (out dtype);
 * epilogues (bias/gelu/glu/residual/softcap) are applied on the final
   reduction step, inside the kernel.
 
-GLU epilogues use *interleaved* packing — columns alternate (gate, up) — so
-one N-block holds complete pairs and can emit its (bm, bn/2) output block
-independently.  Shape-changing epilogues therefore require the scratch-
-accumulator path (enforced in :func:`build_call`).
+GLU epilogues use *chunk-interleaved* packing — columns alternate (gate, up)
+in chunks of ``glu_chunk`` (one vreg's 128 lanes) — so one N-block holds
+complete pairs and can emit its (bm, bn/2) output block independently.
+Shape-changing epilogues therefore always accumulate in the f32 scratch.
 
 Validated against :mod:`repro.kernels.ref` in interpret mode (tests sweep
 shapes × dtypes × schedules).
@@ -32,12 +33,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.schedule import ConcreteSchedule
+from repro.core.schedule import ConcreteSchedule, glu_chunk
+from repro.hw.specs import TPU_V5E
 
 SHAPE_CHANGING = ("matmul_silu_glu", "matmul_gelu_glu", "moe_gemm_silu_glu")
 
 
-def _epilogue_fn(class_id: str, softcap: float) -> Callable[..., jax.Array]:
+def _glu_block(y: jax.Array, act, chunk: int) -> jax.Array:
+    """GLU over one (bm, bn) block of chunk-interleaved (gate, up) columns:
+    static, chunk-aligned lane slices (Mosaic lowers no strided ones)."""
+    return jnp.concatenate(
+        [act(y[:, j:j + chunk]) * y[:, j + chunk:j + 2 * chunk]
+         for j in range(0, y.shape[1], 2 * chunk)], axis=1)
+
+
+def _epilogue_fn(class_id: str, softcap: float,
+                 chunk: int) -> Callable[..., jax.Array]:
     def f(acc, bias=None, residual=None):
         y = acc
         if bias is not None:
@@ -45,9 +56,9 @@ def _epilogue_fn(class_id: str, softcap: float) -> Callable[..., jax.Array]:
         if class_id == "matmul_bias_gelu":
             y = jax.nn.gelu(y)
         elif class_id in ("matmul_silu_glu", "moe_gemm_silu_glu"):
-            y = jax.nn.silu(y[:, 0::2]) * y[:, 1::2]
+            y = _glu_block(y, jax.nn.silu, chunk)
         elif class_id == "matmul_gelu_glu":
-            y = jax.nn.gelu(y[:, 0::2]) * y[:, 1::2]
+            y = _glu_block(y, jax.nn.gelu, chunk)
         elif class_id == "matmul_residual":
             y = y + residual
         elif class_id == "matmul_lmhead_softcap":
@@ -59,7 +70,7 @@ def _epilogue_fn(class_id: str, softcap: float) -> Callable[..., jax.Array]:
 
 def _kernel(x_ref, w_ref, *rest, class_id: str, softcap: float, k_pos: int,
             k_trips: int, use_scratch: bool, has_bias: bool, has_residual: bool,
-            out_dtype):
+            chunk: int, out_dtype):
     """Kernel body shared by all matmul classes.
 
     rest = (*optional bias_ref, *optional residual_ref, o_ref, *optional acc_ref)
@@ -73,7 +84,7 @@ def _kernel(x_ref, w_ref, *rest, class_id: str, softcap: float, k_pos: int,
     acc_ref = rest[i + 1] if use_scratch else None
 
     k_idx = pl.program_id(k_pos)
-    epilogue = _epilogue_fn(class_id, softcap)
+    epilogue = _epilogue_fn(class_id, softcap, chunk)
     partial = jnp.dot(x_ref[...], w_ref[...], preferred_element_type=jnp.float32)
 
     def emit(acc):
@@ -121,7 +132,8 @@ def build_call(
     has_residual: bool = False,
     groups: int = 0,
     out_dtype=jnp.float32,
-    interpret: bool = True,
+    interpret: bool,
+    vmem_limit_bytes: int,
 ):
     """Build a pallas_call for x:(M,K) @ w:(K,N) (+epilogue inputs) -> out.
 
@@ -130,18 +142,14 @@ def build_call(
     """
     bm, bn, bk = cs.t["M"], cs.t["N"], cs.t["K"]
     bm, bn, bk = min(bm, m), min(bn, n), min(bk, k)
-    order = [a for a in cs.order if a in ("M", "N", "K")]
+    order = [a for a in cs.order if a in ("M", "N")] + ["K"]
     trips = {"M": pl.cdiv(m, bm), "N": pl.cdiv(n, bn), "K": pl.cdiv(k, bk)}
     shape_changing = class_id in SHAPE_CHANGING
-    use_scratch = cs.schedule.cache_write and order[-1] == "K"
-    if shape_changing and not use_scratch:
-        # GLU epilogue cannot RMW through a differently-shaped output block.
-        if order[-1] != "K":
-            order = [a for a in order if a != "K"] + ["K"]
-            trips = {"M": pl.cdiv(m, bm), "N": pl.cdiv(n, bn), "K": pl.cdiv(k, bk)}
-        use_scratch = True
-    if shape_changing and bn % 2:
-        raise ValueError(f"GLU epilogue needs even N tile, got {bn}")
+    use_scratch = cs.schedule.cache_write or shape_changing
+    chunk = glu_chunk(n // 2)
+    if shape_changing and bn != n and bn % (2 * chunk):
+        raise ValueError(f"GLU epilogue needs whole (gate, up) pairs of "
+                         f"{2 * chunk} columns per N tile, got {bn}")
 
     pos = {a: i for i, a in enumerate(order)}
     g = int(groups > 0)  # leading expert grid dim for grouped matmul
@@ -178,6 +186,7 @@ def build_call(
         use_scratch=use_scratch,
         has_bias=has_bias,
         has_residual=has_residual,
+        chunk=chunk,
         out_dtype=out_dtype,
     )
 
@@ -209,6 +218,7 @@ def build_call(
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)] if use_scratch else [],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )
 
@@ -216,7 +226,8 @@ def build_call(
 def matmul(x: jax.Array, w: jax.Array, cs: ConcreteSchedule, *,
            class_id: str = "matmul", bias: jax.Array | None = None,
            residual: jax.Array | None = None, softcap: float = 0.0,
-           interpret: bool = True) -> jax.Array:
+           interpret: bool,
+           vmem_limit_bytes: int = TPU_V5E.vmem_capacity) -> jax.Array:
     """Run the kernel: x (M,K) @ w (K,N) with fused epilogue."""
     m, k = x.shape
     n = w.shape[1]
@@ -224,6 +235,7 @@ def matmul(x: jax.Array, w: jax.Array, cs: ConcreteSchedule, *,
         m, n, k, cs, class_id=class_id, softcap=softcap,
         has_bias=bias is not None, has_residual=residual is not None,
         out_dtype=x.dtype, interpret=interpret,
+        vmem_limit_bytes=vmem_limit_bytes,
     )
     args = [x, w]
     if bias is not None:
@@ -234,12 +246,13 @@ def matmul(x: jax.Array, w: jax.Array, cs: ConcreteSchedule, *,
 
 
 def grouped_matmul(x: jax.Array, w: jax.Array, cs: ConcreteSchedule, *,
-                   class_id: str = "moe_gemm", interpret: bool = True) -> jax.Array:
+                   class_id: str = "moe_gemm", interpret: bool,
+                   vmem_limit_bytes: int = TPU_V5E.vmem_capacity) -> jax.Array:
     """Grouped (MoE expert) matmul: x (E,M,K) @ w (E,K,N) -> (E,M,out)."""
     e, m, k = x.shape
     n = w.shape[2]
     call = build_call(
         m, n, k, cs, class_id=class_id, groups=e, out_dtype=x.dtype,
-        interpret=interpret,
+        interpret=interpret, vmem_limit_bytes=vmem_limit_bytes,
     )
     return call(x, w)

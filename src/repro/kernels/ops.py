@@ -7,8 +7,10 @@ transfer-tuned) plumb into execution as a first-class feature:
   container; also the dry-run path, so `.lower()` sees the same sub-
   quadratic structure the Pallas kernels have (chunked attention).
 * ``backend="pallas"`` — the Pallas kernels, realizing the resolved
-  :class:`ConcreteSchedule` as BlockSpecs.  On CPU this runs in interpret
-  mode (functionally exact, used by the tests); on TPU it compiles.
+  :class:`ConcreteSchedule` as BlockSpecs.  On a TPU backend they compile
+  with the provider's target VMEM budget; on any other backend they run in
+  interpret mode (functionally exact, used by the tests).
+  :func:`interpret_mode` reports which, and ``serve.py`` prints it.
 
 Schedule resolution is the :class:`~repro.core.resolution.ResolutionPipeline`
 (service → static map → default) behind a :class:`ScheduleProvider` facade.
@@ -17,10 +19,10 @@ the pre-resolved plan is consulted first — a lock-free dict hit — and only
 unplanned instances walk the pipeline (whose memo cache makes the steady
 state a dict hit as well).
 
-The per-op hot path is kept cheap: the interpret-mode backend probe runs
-once per process, and kernel instances are interned so repeated calls with
-the same shapes reuse one validated :class:`KernelInstance` (and its cached
-workload key) instead of rebuilding it.
+The per-op hot path is kept cheap: the backend probe runs once per process,
+and kernel instances are interned so repeated calls with the same shapes
+reuse one validated :class:`KernelInstance` (and its cached workload key)
+instead of rebuilding it.
 """
 from __future__ import annotations
 
@@ -127,6 +129,12 @@ class ScheduleProvider:
                 self._plan_misses += 1
         return self.pipeline.resolve(instance).concrete
 
+    @property
+    def vmem_limit_bytes(self) -> int:
+        """The target chip's VMEM budget: the legality rule sizes blocks
+        against it and every kernel hands it to Mosaic."""
+        return self.pipeline.spec.vmem_capacity
+
     # -- telemetry ------------------------------------------------------------
     @property
     def plan_hits(self) -> int:
@@ -203,18 +211,17 @@ def _instance(class_id: str, dtype, **params: int) -> KernelInstance:
                      tuple(sorted((k, int(v)) for k, v in params.items())))
 
 
-_INTERPRET: bool | None = None
+@functools.cache
+def interpret_mode() -> bool:
+    """Whether the ``pallas`` backend runs its kernels in interpret mode:
+    exactly when JAX's default backend is not a TPU.  The probe is
+    process-wide and stable, so it runs once."""
+    return jax.default_backend() != "tpu"
 
 
-def _interpret() -> bool:
-    """Pallas interpret mode: on unless a real TPU backend is present.
-
-    The backend probe is process-wide and stable, so it runs once instead of
-    on every op call."""
-    global _INTERPRET
-    if _INTERPRET is None:
-        _INTERPRET = jax.default_backend() != "tpu"
-    return _INTERPRET
+def _kernel_kw(provider: ScheduleProvider) -> dict:
+    return {"interpret": interpret_mode(),
+            "vmem_limit_bytes": provider.vmem_limit_bytes}
 
 
 # ---------------------------------------------------------------------------
@@ -238,9 +245,9 @@ def matmul(x: jax.Array, w: jax.Array, *, class_id: str = "matmul",
     x2 = x.reshape(m, k)
     res2 = residual.reshape(m, -1) if residual is not None else None
     inst = _instance(class_id, x.dtype, M=m, N=n, K=k)
-    cs = _resolve(provider).get(inst)
-    y = _mm.matmul(x2, w, cs, class_id=class_id, bias=bias, residual=res2,
-                   softcap=softcap, interpret=_interpret())
+    provider = _resolve(provider)
+    y = _mm.matmul(x2, w, provider.get(inst), class_id=class_id, bias=bias,
+                   residual=res2, softcap=softcap, **_kernel_kw(provider))
     return y.reshape(*lead, y.shape[-1])
 
 
@@ -254,8 +261,9 @@ def moe_gemm(x: jax.Array, w: jax.Array, *, class_id: str = "moe_gemm",
     e, m, k = x.shape
     n = w.shape[2]
     inst = _instance(class_id, x.dtype, M=m * e, N=n, K=k, E=e)
-    cs = _resolve(provider).get(inst)
-    return _mm.grouped_matmul(x, w, cs, class_id=class_id, interpret=_interpret())
+    provider = _resolve(provider)
+    return _mm.grouped_matmul(x, w, provider.get(inst), class_id=class_id,
+                              **_kernel_kw(provider))
 
 
 # ---------------------------------------------------------------------------
@@ -276,10 +284,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     b, hq, sq, d = q.shape
     inst = _instance(class_id, q.dtype, Q=sq, KV=k.shape[2], H=hq, D=d, B=b,
                      window=window)
-    cs = _resolve(provider).get(inst)
-    return _fa.flash_attention(q, k, v, cs, causal=causal, window=window,
-                               softcap=softcap, q_offset=q_offset,
-                               interpret=_interpret())
+    provider = _resolve(provider)
+    return _fa.flash_attention(q, k, v, provider.get(inst), causal=causal,
+                               window=window, softcap=softcap, q_offset=q_offset,
+                               **_kernel_kw(provider))
 
 
 # ---------------------------------------------------------------------------
@@ -295,8 +303,9 @@ def rwkv6(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array, u: jax.Array,
         return ref.rwkv6_scan(r, k, v, w, u, state)
     b, h, t, d = r.shape
     inst = _instance("rwkv6_scan", r.dtype, T=t, C=h * d, D=d, B=b)
-    cs = _resolve(provider).get(inst)
-    return _rw.rwkv6_scan(r, k, v, w, u, state, cs, interpret=_interpret())
+    provider = _resolve(provider)
+    return _rw.rwkv6_scan(r, k, v, w, u, state, provider.get(inst),
+                          **_kernel_kw(provider))
 
 
 def rglru(x: jax.Array, a: jax.Array, state: jax.Array, *,
@@ -307,5 +316,6 @@ def rglru(x: jax.Array, a: jax.Array, state: jax.Array, *,
         return ref.rglru_scan(x, a, state)
     b, t, c = x.shape
     inst = _instance("rglru_scan", x.dtype, T=t, C=c, B=b)
-    cs = _resolve(provider).get(inst)
-    return _rg.rglru_scan(x, a, state, cs, interpret=_interpret())
+    provider = _resolve(provider)
+    return _rg.rglru_scan(x, a, state, provider.get(inst),
+                          **_kernel_kw(provider))

@@ -14,21 +14,26 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.core.schedule import glu_chunk
+
 # ---------------------------------------------------------------------------
 # Matmul + fused epilogues
 # ---------------------------------------------------------------------------
 
 
 def _glu(y: jax.Array, act: Callable[[jax.Array], jax.Array]) -> jax.Array:
-    """Interleaved GLU: columns are packed (gate, up, gate, up, ...).
+    """Chunk-interleaved GLU: columns are packed (gate chunk, up chunk, ...)
+    in chunks of :func:`~repro.core.schedule.glu_chunk` columns.
 
     The Pallas kernel applies the epilogue per N-block, which requires the
     gate/up pair to live in the same block — hence interleaved packing (the
     framework owns the weight layout; see models/common.py pack_glu).
     """
-    g = y[..., 0::2]
-    u = y[..., 1::2]
-    return act(g) * u
+    *lead, n = y.shape
+    f = n // 2
+    c = glu_chunk(f)
+    y = y.reshape(*lead, f // c, 2, c)
+    return (act(y[..., 0, :]) * y[..., 1, :]).reshape(*lead, f)
 
 
 def apply_epilogue(y: jax.Array, class_id: str, *, bias: jax.Array | None = None,

@@ -1,4 +1,6 @@
 import os
+# Host-only: 512 virtual CPU devices, never a chip a parent process may hold.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 import argparse        # noqa: E402

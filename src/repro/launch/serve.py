@@ -42,9 +42,11 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_arch, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core.database import ScheduleDB
 from repro.fleet.traffic import sample_prompts
-from repro.kernels.ops import ScheduleProvider, set_default_provider, use_backend
+from repro.kernels.ops import (ScheduleProvider, interpret_mode,
+                               set_default_provider, use_backend)
 from repro.targets import DEFAULT_TARGET, list_targets
 from repro.models.build import build_model
 from repro.serving import ServingEngine, SlotsFull
@@ -103,6 +105,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--metrics-out", default="",
                     help="write the engine's resolution metrics as JSON")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.preset == "smoke":
@@ -164,6 +167,11 @@ def main(argv=None) -> dict:
     toks = sum(len(r.generated) for r in done)
     result = {"requests": len(done), "decode_steps": steps,
               "tokens": toks, "tok_per_s": round(toks / dt, 1),
+              "serve_s": dt, "backend": args.backend,
+              # Pallas kernels compile only on a TPU backend; anywhere else
+              # they run in interpret mode (null: the ref backend runs none).
+              "pallas_interpret": (interpret_mode() if args.backend == "pallas"
+                                   else None),
               "target": args.target,
               "schedule_hits": provider.hits, "schedule_misses": provider.misses,
               "resolution": provider.stats(),

@@ -74,6 +74,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_arch, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.fleet import (
     POLICIES,
     Autoscaler,
@@ -217,6 +218,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--metrics-out", default="",
                     help="write the fleet-wide metrics registry as JSON")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.preset == "smoke":

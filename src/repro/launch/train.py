@@ -15,6 +15,8 @@ config, 1-device mesh) and, via the dry-run, on the production meshes.
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import functools
 import json
 import os
 import time
@@ -31,6 +33,7 @@ from repro.distributed import sharding as shd
 from repro.distributed.context import activation_sharding, set_remat_policy
 from repro.kernels.ops import ScheduleProvider
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.models.build import build_model
 from repro.optim.adamw import AdamWConfig
@@ -50,16 +53,24 @@ def main(argv=None) -> dict:
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--tuning-db", default="", help="transfer-tuned ScheduleDB json")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the config to this many layers (0: its own depth)")
+    ap.add_argument("--devices", type=int, default=0,
+                    help="train on the first N devices (0: all); a mesh "
+                         "spans them when N > 1")
     ap.add_argument("--mesh-model", type=int, default=1)
     ap.add_argument("--strategy", choices=["auto", "dp", "fsdp_tp"], default="auto",
                     help="auto: pure-DP/ZeRO-3 for small models (EXPERIMENTS §Perf it-7)")
     ap.add_argument("--remat-policy", choices=["full", "dots"], default="full")
     ap.add_argument("--log-every", type=int, default=5)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.preset == "smoke":
         cfg = reduced(cfg)
+    if args.layers:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
     model = build_model(cfg)
 
     provider = None
@@ -68,14 +79,17 @@ def main(argv=None) -> dict:
         provider = ScheduleProvider({r.instance.workload_key(): r.schedule
                                      for r in db.records()})
 
-    mesh = make_test_mesh(model=args.mesh_model) if len(jax.devices()) > 1 else None
+    n_devices = args.devices or len(jax.devices())
+    mesh = (make_test_mesh(n_devices, model=args.mesh_model)
+            if n_devices > 1 else None)
 
-    params = model.init(jax.random.PRNGKey(0))
-    opt_state = steps_mod.init_opt_state(params, compress_grads=args.compress_grads)
     opt_cfg = AdamWConfig(peak_lr=args.lr, warmup_steps=max(args.steps // 10, 2),
                           total_steps=args.steps)
     step_fn = steps_mod.make_train_step(model, opt_cfg, grad_accum=args.grad_accum,
                                         compress_grads=args.compress_grads)
+    init_opt = functools.partial(steps_mod.init_opt_state,
+                                 compress_grads=args.compress_grads)
+    key = jax.random.PRNGKey(0)
 
     if mesh is not None:
         if args.strategy == "dp":
@@ -84,16 +98,21 @@ def main(argv=None) -> dict:
             dp_only = False
         else:
             dp_only = shd.dp_dominant(cfg, mesh, kind="train", global_batch=args.batch)
-        p_shard = shd.param_shardings(jax.eval_shape(lambda: params), cfg, mesh, dp_only)
+        p_shard = shd.param_shardings(jax.eval_shape(model.init, key), cfg, mesh,
+                                      dp_only)
         o_shard = {**shd.opt_state_shardings(p_shard, mesh)}
         if args.compress_grads:
             o_shard["residuals"] = p_shard
-        params = jax.device_put(params, p_shard)
-        opt_state = jax.device_put(opt_state, o_shard)
+        # Params and optimizer state are born sharded: at published widths
+        # the f32 state alone outgrows one chip.
+        params = jax.jit(model.init, out_shardings=p_shard)(key)
+        opt_state = jax.jit(init_opt, out_shardings=o_shard)(params)
         jitted = jax.jit(step_fn, in_shardings=(p_shard, o_shard, None),
                          out_shardings=(p_shard, o_shard, None), donate_argnums=(0, 1))
         act = shd.activation_sharding(mesh, cfg, dp_only)
     else:
+        params = jax.jit(model.init)(key)
+        opt_state = jax.jit(init_opt)(params)
         jitted = jax.jit(step_fn, donate_argnums=(0, 1))
         act = None
 
@@ -136,6 +155,7 @@ def main(argv=None) -> dict:
         manager.wait()
     result = {"first_loss": losses[0] if losses else None,
               "last_loss": losses[-1] if losses else None,
+              "losses": losses, "devices": n_devices,
               "steps": len(losses), "stragglers": len(monitor.flagged)}
     print(json.dumps(result))
     return result

@@ -9,6 +9,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.schedule import glu_chunk
+
 
 def dtype_of(name: str):
     return {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[name]
@@ -29,11 +31,15 @@ def embed_init(key: jax.Array, vocab: int, dim: int, dtype) -> jax.Array:
 
 
 def pack_glu(w_gate: jax.Array, w_up: jax.Array) -> jax.Array:
-    """Interleave gate/up columns: (K, F) + (K, F) -> (K, 2F) with columns
-    (g0, u0, g1, u1, ...).  Required by the fused GLU kernel epilogue —
-    each N-block then holds complete (gate, up) pairs."""
+    """Interleave gate/up column chunks: (K, F) + (K, F) -> (K, 2F) with
+    columns (gate chunk 0, up chunk 0, gate chunk 1, ...), chunks of
+    :func:`~repro.core.schedule.glu_chunk` columns.  Required by the fused
+    GLU kernel epilogue — each N-block then holds complete (gate, up) pairs
+    it splits with lane-aligned slices."""
     k, f = w_gate.shape
-    return jnp.stack([w_gate, w_up], axis=2).reshape(k, 2 * f)
+    c = glu_chunk(f)
+    return jnp.stack([w_gate.reshape(k, f // c, c), w_up.reshape(k, f // c, c)],
+                     axis=2).reshape(k, 2 * f)
 
 
 def glu_init(key: jax.Array, d: int, f: int, dtype) -> jax.Array:

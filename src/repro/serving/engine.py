@@ -75,6 +75,15 @@ class Request:
     request_class: str = ""
 
 
+def prefill_bucket_lengths(cap: int) -> list[int]:
+    """The prefill pad lengths up to ``cap``: powers of two, then ``cap``."""
+    out, b = [], 1
+    while b < cap:
+        out.append(b)
+        b *= 2
+    return out + [cap]
+
+
 class ServingEngine:
     def __init__(self, model: Model, params: Any, *, slots: int, max_len: int,
                  extras: dict | None = None, provider=None,
@@ -164,12 +173,7 @@ class ServingEngine:
         """Every pad length prefill can be traced at (for plan coverage)."""
         if not self.prefill_buckets:
             return []
-        out, b = [], 1
-        while b < self._bucket_cap:
-            out.append(b)
-            b *= 2
-        out.append(self._bucket_cap)
-        return out
+        return prefill_bucket_lengths(self._bucket_cap)
 
     @property
     def prefill_trace_count(self) -> int:

@@ -11,6 +11,7 @@ from repro.targets.registry import (
     list_targets,
     register_target,
     resolve_target,
+    target_for_device,
     target_name,
 )
 
@@ -21,5 +22,6 @@ __all__ = [
     "list_targets",
     "register_target",
     "resolve_target",
+    "target_for_device",
     "target_name",
 ]

@@ -73,6 +73,23 @@ def list_targets() -> list[str]:
     return sorted(_REGISTRY)
 
 
+#: ``jax.Device.device_kind`` -> registered target.  A real v5e reports
+#: "TPU v5 lite"; it is the ``tpu-v5e`` target, not the made-up
+#: ``tpu-v5e-lite`` edge part.
+DEVICE_KIND_TARGETS = {"TPU v5 lite": "tpu-v5e"}
+
+
+def target_for_device(device_kind: str) -> Target:
+    """The registered target a device runs as; an unknown kind is an error,
+    never a default."""
+    try:
+        return get_target(DEVICE_KIND_TARGETS[device_kind])
+    except KeyError:
+        raise KeyError(
+            f"no target for device kind {device_kind!r}; known kinds: "
+            f"{sorted(DEVICE_KIND_TARGETS)}") from None
+
+
 def target_name(target: "str | Target | ChipSpec | None") -> str:
     """The namespace key for a target-ish value (no registry lookup).
 

@@ -1,9 +1,12 @@
 """Auto-scheduler (Ansor analogue) behaviour."""
 import random
 
+import pytest
+
 from repro.core.autoscheduler import (
     KernelTask,
     Surrogate,
+    crossover,
     featurize,
     mutate,
     random_schedule,
@@ -11,7 +14,7 @@ from repro.core.autoscheduler import (
     tune_model,
 )
 from repro.core.cost_model import kernel_seconds, measure
-from repro.core.schedule import default_schedule, is_valid
+from repro.core.schedule import REDUCTION_AXIS, default_schedule, is_valid
 from repro.core.workload import KernelInstance, KernelUse
 
 
@@ -34,6 +37,27 @@ def test_mutation_preserves_validity():
     for _ in range(50):
         s = mutate(s, inst, rng)
         assert is_valid(s, inst), s
+
+
+@pytest.mark.parametrize("inst", [
+    g(512, 512, 512),
+    KernelInstance.make("moe_gemm_silu_glu", M=512, N=1024, K=256, E=8),
+    KernelInstance.make("flash_attention_causal", Q=256, KV=256, H=8, D=128),
+    KernelInstance.make("rglru_scan", T=256, C=2560),
+], ids=lambda i: i.class_id)
+def test_search_keeps_reduction_innermost(inst):
+    """Proposals, mutations and crossovers only emit the order the kernels
+    run, so no trial is spent on a schedule that is never realized."""
+    rng = random.Random(2)
+    red = REDUCTION_AXIS[inst.family]
+    pool = [random_schedule(inst, rng) for _ in range(20)]
+    for _ in range(40):
+        pool.append(mutate(rng.choice(pool), inst, rng))
+        pool.append(crossover(*rng.sample(pool, 2), rng))
+    assert all(s.order[-1] == red for s in pool)
+    # the outer axes are still permuted where there are two or more
+    if len(inst.axes) > 2:
+        assert len({s.order for s in pool}) > 1
 
 
 def test_tuning_improves_over_default():

@@ -34,11 +34,37 @@ def test_bigger_tiles_reduce_hbm_traffic():
 
 
 def test_order_changes_traffic():
-    """Reorder (paper primitive) must change the modeled HBM bytes."""
-    t = {"M": 64, "N": 128, "K": 128}
+    """Reorder (paper primitive) of the outer axes must change the modeled
+    HBM bytes: with K in one block, the operand indexed by the outer axis
+    alone stays resident across the inner one."""
+    t = {"M": 64, "N": 128, "K": 1024}
     a = evaluate(concretize(Schedule.make("matmul", t, order=("M", "N", "K")), g()))
-    b = evaluate(concretize(Schedule.make("matmul", t, order=("M", "K", "N")), g()))
+    b = evaluate(concretize(Schedule.make("matmul", t, order=("N", "M", "K")), g()))
     assert a.hbm_bytes != b.hbm_bytes
+    # M outer streams all of w once per M block (16), N outer all of x once
+    # per N block (8): N outer moves fewer bytes
+    assert b.hbm_bytes < a.hbm_bytes
+
+
+@pytest.mark.parametrize("class_id,tiles,order,legal_order,extents", [
+    ("matmul", {"M": 128, "N": 128, "K": 128}, ("M", "K", "N"), ("N", "M", "K"),
+     dict(M=1024, N=1024, K=1024)),
+    ("flash_attention_causal", {"Q": 128, "KV": 128}, ("KV", "Q"), ("Q", "KV"),
+     dict(Q=1024, KV=1024, H=8, D=128)),
+    ("rglru_scan", {"T": 128, "C": 512}, ("T", "C"), ("C", "T"),
+     dict(T=1024, C=2560)),
+])
+def test_reduction_not_innermost_invalid(class_id, tiles, order, legal_order,
+                                         extents):
+    """The kernels run the reduction innermost only; no other order is
+    priced (parallel=0 marks nothing parallel, isolating the order rule)."""
+    inst = KernelInstance.make(class_id, **extents)
+    sched = Schedule.make(class_id, tiles, order=order, parallel=0)
+    with pytest.raises(ScheduleInvalid, match="not innermost"):
+        evaluate(concretize(sched, inst))
+    assert not measure(inst, sched).valid
+    legal = Schedule.make(class_id, tiles, order=legal_order, parallel=0)
+    assert measure(inst, legal).valid
 
 
 def test_vmem_overflow_invalid():
@@ -57,11 +83,11 @@ def test_parallel_reduction_invalid():
 
 
 def test_alignment_penalty():
-    """Misaligned (non-128) N tiles waste MXU lanes -> slower compute term."""
-    aligned = evaluate(concretize(Schedule.make("matmul", {"M": 128, "N": 128, "K": 128}), g()))
+    """Misaligned (non-128) N tiles waste MXU lanes -> slower compute term.
+    The block rule leaves the full extent as the only misaligned N tile."""
     odd = KernelInstance.make("matmul", M=1024, N=1000, K=1024)
-    mis = evaluate(concretize(Schedule.make("matmul", {"M": 128, "N": 8, "K": 128}),
-                              odd, mode="adaptive"))
+    aligned = evaluate(concretize(Schedule.make("matmul", {"M": 128, "N": 128, "K": 128}), odd))
+    mis = evaluate(concretize(Schedule.make("matmul", {"M": 128, "N": 1000, "K": 128}), odd))
     assert mis.compute_s > aligned.compute_s
 
 

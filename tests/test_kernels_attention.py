@@ -37,7 +37,8 @@ def test_kernel_matches_naive(sq, bq, bkv, causal, window, softcap, group):
     hq = hkv * group
     q, k, v = _data(b, hq, hkv, sq, sq, d)
     cs = _cs(sq, sq, bq, bkv)
-    y = fa.flash_attention(q, k, v, cs, causal=causal, window=window, softcap=softcap)
+    y = fa.flash_attention(q, k, v, cs, causal=causal, window=window, softcap=softcap,
+                           interpret=True)
     yr = ref.attention(q, k, v, causal=causal, window=window, softcap=softcap)
     np.testing.assert_allclose(y, yr, rtol=2e-4, atol=2e-4)
 
@@ -57,7 +58,7 @@ def test_decode_q1_with_offset():
     q, k, v = _data(2, 4, 2, 1, 32, 16, seed=4)
     cs = _cs(1, 32, 1, 8)
     for off in (0, 7, 31):
-        y = fa.flash_attention(q, k, v, cs, causal=True, q_offset=off)
+        y = fa.flash_attention(q, k, v, cs, causal=True, q_offset=off, interpret=True)
         yr = ref.attention(q, k, v, causal=True, q_offset=off)
         np.testing.assert_allclose(y, yr, rtol=2e-4, atol=2e-4)
 
@@ -65,7 +66,7 @@ def test_decode_q1_with_offset():
 def test_cross_attention_lengths_differ():
     q, k, v = _data(1, 4, 4, 8, 40, 16, seed=5)
     cs = _cs(8, 40, 4, 8, cls="flash_attention_cross")
-    y = fa.flash_attention(q, k, v, cs, causal=False)
+    y = fa.flash_attention(q, k, v, cs, causal=False, interpret=True)
     yr = ref.attention(q, k, v, causal=False)
     np.testing.assert_allclose(y, yr, rtol=2e-4, atol=2e-4)
 
@@ -74,14 +75,14 @@ def test_fully_masked_rows_are_finite():
     """Window smaller than block: rows with no visible kv must not NaN."""
     q, k, v = _data(1, 2, 2, 16, 16, 8, seed=6)
     cs = _cs(16, 16, 8, 8)
-    y = fa.flash_attention(q, k, v, cs, causal=True, window=2)
+    y = fa.flash_attention(q, k, v, cs, causal=True, window=2, interpret=True)
     assert bool(jnp.isfinite(y).all())
 
 
 def test_bf16_kernel():
     q, k, v = _data(1, 2, 2, 16, 16, 16, seed=7, dtype=jnp.bfloat16)
     cs = _cs(16, 16, 8, 8)
-    y = fa.flash_attention(q, k, v, cs, causal=True)
+    y = fa.flash_attention(q, k, v, cs, causal=True, interpret=True)
     yr = ref.attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(yr, np.float32),
                                rtol=3e-2, atol=3e-2)

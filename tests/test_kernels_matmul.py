@@ -58,11 +58,47 @@ def test_epilogues_match_oracle(class_id, needs):
     residual = jnp.asarray(r.normal(size=(m, out_n)), jnp.float32) if needs == "residual" else None
     softcap = 30.0 if "softcap" in class_id else 0.0
     inst = KernelInstance.make(class_id, M=m, N=n, K=k, dtype="float32")
-    cs = concretize(Schedule.make(class_id, {"M": 16, "N": 16, "K": 16}), inst)
+    # GLU tiles hold whole (gate, up) pairs: at n=64 that is the full width
+    tn = n if "glu" in class_id else 16
+    cs = concretize(Schedule.make(class_id, {"M": 16, "N": tn, "K": 16}), inst)
     y = mk.matmul(x, w, cs, class_id=class_id, bias=bias, residual=residual,
                   softcap=softcap, interpret=True)
     yr = ref.matmul(x, w, class_id, bias=bias, residual=residual, softcap=softcap)
     np.testing.assert_allclose(y, yr, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("class_id", ["matmul_silu_glu", "matmul_gelu_glu"])
+@pytest.mark.parametrize("tn", [256, 512])
+def test_glu_chunk_pairs_match_oracle(class_id, tn):
+    """n=512 packs two (gate, up) pairs of 128-column chunks: a 256-wide N
+    tile runs one pair per block, the full width both in one block; M and K
+    run several blocks, the last M block partial."""
+    m, n, k = 40, 512, 48
+    x, w = _data(m, n, k, jnp.float32)
+    inst = KernelInstance.make(class_id, M=m, N=n, K=k, dtype="float32")
+    cs = concretize(Schedule.make(class_id, {"M": 16, "N": tn, "K": 16}), inst)
+    assert cs.t["N"] == tn and cs.g["N"] == n // tn
+    y = mk.matmul(x, w, cs, class_id=class_id, interpret=True)
+    assert y.shape == (m, n // 2)
+    np.testing.assert_allclose(y, ref.matmul(x, w, class_id), rtol=2e-4, atol=2e-4)
+
+
+def test_glu_packing_matches_separate_projections():
+    """pack_glu's chunk interleave + the GLU epilogue equal act(x@g) * (x@u)."""
+    from repro.models.common import pack_glu
+
+    m, k, f = 16, 32, 256
+    r = np.random.default_rng(11)
+    x, wg, wu = (jnp.asarray(r.normal(size=s), jnp.float32)
+                 for s in ((m, k), (k, f), (k, f)))
+    w = pack_glu(wg, wu)
+    inst = KernelInstance.make("matmul_silu_glu", M=m, N=2 * f, K=k, dtype="float32")
+    cs = concretize(Schedule.make("matmul_silu_glu", {"M": 8, "N": 256, "K": 16}), inst)
+    y = mk.matmul(x, w, cs, class_id="matmul_silu_glu", interpret=True)
+    want = jax.nn.silu(x @ wg) * (x @ wu)
+    np.testing.assert_allclose(y, want, rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(ref.matmul(x, w, "matmul_silu_glu"), want,
+                               rtol=2e-4, atol=2e-4)
 
 
 def test_bfloat16_tolerance():
@@ -90,11 +126,12 @@ def test_grouped_matmul_matches_vmapped_oracle():
 
 
 def test_glu_forces_scratch_on_bad_order():
-    """GLU epilogues silently canonicalize to K-inner scratch accumulation."""
-    m, n, k = 32, 32, 32
+    """GLU epilogues silently canonicalize to K-inner scratch accumulation
+    (two N blocks of one (gate, up) chunk pair each)."""
+    m, n, k = 32, 512, 32
     x, w = _data(m, n, k, jnp.float32)
     inst = KernelInstance.make("matmul_silu_glu", M=m, N=n, K=k, dtype="float32")
-    cs = concretize(Schedule.make("matmul_silu_glu", {"M": 16, "N": 16, "K": 16},
+    cs = concretize(Schedule.make("matmul_silu_glu", {"M": 16, "N": 256, "K": 16},
                                   order=("K", "M", "N"), cache_write=False), inst)
     y = mk.matmul(x, w, cs, class_id="matmul_silu_glu", interpret=True)
     np.testing.assert_allclose(y, ref.matmul(x, w, "matmul_silu_glu"),

@@ -33,7 +33,7 @@ def test_rwkv6_kernel_matches_oracle(t, ct, h, d):
     inst = KernelInstance.make("rwkv6_scan", T=t, C=h * d, D=d, B=b, dtype="float32")
     cs = concretize(Schedule.make("rwkv6_scan", {"T": ct, "C": h * d}, order=("C", "T")),
                     inst, mode="adaptive")
-    y, sT = rw.rwkv6_scan(r, k, v, w, u, s0, cs)
+    y, sT = rw.rwkv6_scan(r, k, v, w, u, s0, cs, interpret=True)
     yr, sTr = ref.rwkv6_scan(r, k, v, w, u, s0)
     np.testing.assert_allclose(y, yr, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(sT, sTr, rtol=1e-5, atol=1e-5)
@@ -51,7 +51,7 @@ def test_rglru_kernel_matches_oracle(t, ct, c, bc):
     inst = KernelInstance.make("rglru_scan", T=t, C=c, B=b, dtype="float32")
     cs = concretize(Schedule.make("rglru_scan", {"T": ct, "C": bc}, order=("C", "T")),
                     inst, mode="adaptive")
-    y, hT = rg.rglru_scan(x, a, h0, cs)
+    y, hT = rg.rglru_scan(x, a, h0, cs, interpret=True)
     yr, hTr = ref.rglru_scan(x, a, h0)
     np.testing.assert_allclose(y, yr, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(hT, hTr, rtol=1e-5, atol=1e-5)
@@ -71,7 +71,7 @@ def test_chunking_invariance():
         inst = KernelInstance.make("rwkv6_scan", T=t, C=h * d, D=d, B=b, dtype="float32")
         cs = concretize(Schedule.make("rwkv6_scan", {"T": ct, "C": h * d},
                                       order=("C", "T")), inst)
-        y, _ = rw.rwkv6_scan(r, k, v, w, u, s0, cs)
+        y, _ = rw.rwkv6_scan(r, k, v, w, u, s0, cs, interpret=True)
         outs.append(np.asarray(y))
     np.testing.assert_array_equal(outs[0], outs[1])
     np.testing.assert_array_equal(outs[0], outs[2])

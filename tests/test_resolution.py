@@ -22,11 +22,11 @@ from repro.kernels.ops import ScheduleProvider
 from repro.service import ScheduleRegistry, TuningService
 
 
-def make_instance(m=64, n=64, k=64, dtype="float32"):
+def make_instance(m=64, n=256, k=256, dtype="float32"):
     return KernelInstance.make("matmul", M=m, N=n, K=k, dtype=dtype)
 
 
-def make_schedule(tm=32, tn=32, tk=32, **kw):
+def make_schedule(tm=32, tn=128, tk=128, **kw):
     return Schedule.make("matmul", tiles={"M": tm, "N": tn, "K": tk}, **kw)
 
 
@@ -51,8 +51,8 @@ def publish(registry, inst, sched, seconds=1e-6, model_id="donor",
 
 def test_stage_order_service_beats_static_beats_default(tmp_path):
     inst = make_instance()
-    svc_sched = make_schedule(32, 32, 32)
-    static_sched = make_schedule(16, 16, 16)
+    svc_sched = make_schedule(32, 128, 128)
+    static_sched = make_schedule(16, 256, 256)
     registry, service = make_service(tmp_path)
     publish(registry, inst, svc_sched)
 
@@ -140,7 +140,7 @@ def test_changed_since_migrates_unchanged_entries(tmp_path):
     pipe.resolve(inst_b)
 
     # Publish through the service: the pipeline can attribute the bump.
-    sched = make_schedule(64, 64, 64)
+    sched = make_schedule(64, 256, 256)
     service._publish(inst_a, sched,
                      service.runner.seconds(inst_a, sched), "donor")
     assert pipe.resolve(inst_a).tier == "exact"
@@ -189,9 +189,10 @@ def test_external_publish_clears_cache_conservatively(tmp_path):
 
 
 def test_cache_key_mode_dimension():
-    inst = make_instance(64, 64, 64)
-    # 48 does not divide 64 on the reduction axis: strict-invalid, adaptive ok
-    sched = make_schedule(32, 32, 48)
+    inst = make_instance(64, 256, 768)
+    # 512 does not divide 768 on the reduction axis: strict-invalid, adaptive
+    # snaps it to 384 (still lane-aligned, so legal on the chip)
+    sched = make_schedule(32, 128, 512)
     pipe = ResolutionPipeline.build(
         schedule_map={inst.workload_key(): sched})
     assert pipe.resolve(inst, mode="strict").tier == "default"

@@ -1,0 +1,224 @@
+"""Compile-only checks of the main-path kernels for a TPU v5e.
+
+Nothing runs: each test lowers a kernel at gemma2-2b's real widths and asks
+the TPU compiler (Mosaic) for a chip that is described, not attached.  That
+catches what interpret mode cannot — blocks Mosaic refuses, VMEM overflow,
+values captured into a kernel — at no chip time.  Alongside, the legality
+rule (:mod:`repro.core.legality`) must reject exactly the schedules the
+compiler would refuse, before they reach it.
+
+The topology is described inside a module-scoped fixture, never at import:
+only the worker that runs this file loads the TPU library.
+"""
+import random
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.base import get_arch
+from repro.core import legality
+from repro.core.autoscheduler import random_schedule
+from repro.core.cost_model import measure
+from repro.core.resolution import ResolutionPipeline, StaticMapStage, DefaultStage, plan_serving
+from repro.core.schedule import Schedule, ScheduleInvalid, concretize
+from repro.core.workload import KernelInstance
+from repro.hw.specs import TPU_V5E
+from repro.kernels import flash_attention as fa
+from repro.kernels import matmul as mk
+from repro.kernels import rglru_scan as rg
+from repro.kernels import rwkv6_scan as rw
+
+GEMMA = get_arch("gemma2-2b")
+D, F, V, HD = GEMMA.d_model, GEMMA.d_ff, GEMMA.vocab_size, GEMMA.head_dim
+HQ, HKV = GEMMA.n_heads, GEMMA.n_kv_heads
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # Compiles for a described chip cannot be read back without one: keep
+    # them out of any persistent cache another test may have switched on.
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        jax.config.update("jax_enable_compilation_cache", prev)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _matmul_cs(class_id, m, n, k, tiles, **kw):
+    inst = KernelInstance.make(class_id, M=m, N=n, K=k)
+    cs = concretize(Schedule.make(class_id, tiles, **kw), inst)
+    legality.check(cs, TPU_V5E)
+    return cs
+
+
+def _compile_matmul(one_chip, cs, softcap=0.0):
+    p = cs.instance.p
+    m, n, k = p["M"], p["N"], p["K"]
+    return _compile(
+        lambda x, w: mk.matmul(x, w, cs, class_id=cs.instance.class_id,
+                               softcap=softcap, interpret=False),
+        _sds(one_chip, (m, k)), _sds(one_chip, (k, n)))
+
+
+@pytest.mark.parametrize("class_id,m,n,k,tiles,softcap", [
+    # attention q projection, 256-token prefill bucket
+    ("matmul", 256, HQ * HD, D, {"M": 128, "N": 256, "K": 384}, 0.0),
+    # GeGLU up-projection (interleaved gate/up), 8-slot decode batch
+    ("matmul_gelu_glu", 8, 2 * F, D, {"M": 8, "N": 1024, "K": 768}, 0.0),
+    # softcapped tied lm head over the full vocabulary
+    ("matmul_lmhead_softcap", 8, V, D, {"M": 8, "N": 1024, "K": D}, 30.0),
+])
+def test_matmul_classes_compile(one_chip, class_id, m, n, k, tiles, softcap):
+    cs = _matmul_cs(class_id, m, n, k, tiles)
+    compiled = _compile_matmul(one_chip, cs, softcap)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("class_id,window,softcap", [
+    ("flash_attention_causal", 0, 0.0),
+    ("flash_attention_local", GEMMA.window, 0.0),
+    ("flash_attention_softcap", 0, GEMMA.attn_softcap),
+])
+def test_flash_attention_compiles_with_traced_offset(one_chip, class_id, window,
+                                                     softcap):
+    sq = skv = 256
+    inst = KernelInstance.make(class_id, Q=sq, KV=skv, H=HQ, D=HD, B=1)
+    cs = concretize(Schedule.make(class_id, {"Q": 128, "KV": 128}), inst)
+    legality.check(cs, TPU_V5E)
+    # q_offset is traced, as in chunked prefill: it must enter the kernel as
+    # an operand, not as a captured constant
+    _compile(lambda q, k, v, off: fa.flash_attention(
+        q, k, v, cs, causal=True, window=window, softcap=softcap, q_offset=off,
+        interpret=False),
+        _sds(one_chip, (1, HQ, sq, HD)), _sds(one_chip, (1, HKV, skv, HD)),
+        _sds(one_chip, (1, HKV, skv, HD)), _sds(one_chip, (), jnp.int32))
+
+
+def test_rglru_scan_compiles_batched(one_chip):
+    b, t, c = 2, 256, 2560   # recurrentgemma-2b width, batch > 1
+    inst = KernelInstance.make("rglru_scan", T=t, C=c, B=b)
+    cs = concretize(Schedule.make("rglru_scan", {"T": 128, "C": 512},
+                                  order=("C", "T")), inst)
+    legality.check(cs, TPU_V5E)
+    _compile(lambda x, a, s: rg.rglru_scan(x, a, s, cs, interpret=False),
+             _sds(one_chip, (b, t, c)), _sds(one_chip, (b, t, c)),
+             _sds(one_chip, (b, c), jnp.float32))
+
+
+def test_rwkv6_scan_compiles_multihead(one_chip):
+    b, h, t, d = 2, 32, 256, 64   # rwkv6-1.6b heads, batch > 1
+    inst = KernelInstance.make("rwkv6_scan", T=t, C=h * d, D=d, B=b)
+    cs = concretize(Schedule.make("rwkv6_scan", {"T": 64, "C": h * d},
+                                  order=("C", "T")), inst)
+    legality.check(cs, TPU_V5E)
+    x = _sds(one_chip, (b, h, t, d))
+    _compile(lambda r, k, v, w, u, s: rw.rwkv6_scan(r, k, v, w, u, s, cs,
+                                                    interpret=False),
+             x, x, x, x, _sds(one_chip, (h, d)),
+             _sds(one_chip, (b, h, d, d), jnp.float32))
+
+
+def test_schedule_at_vmem_budget_edge_compiles(one_chip):
+    """The largest tiles the rule admits compile under the budget every
+    pallas_call hands Mosaic; one step larger is refused by the rule."""
+    cs = _matmul_cs("matmul", 4096, 8192, D, {"M": 1024, "N": 4096, "K": D})
+    assert legality.vmem_bytes(cs, TPU_V5E) > 0.9 * TPU_V5E.vmem_capacity
+    _compile_matmul(one_chip, cs)
+    with pytest.raises(ScheduleInvalid, match="VMEM"):
+        _matmul_cs("matmul", 4096, 8192, D, {"M": 2048, "N": 4096, "K": D})
+
+
+def test_illegal_tile_rejected_before_the_compiler(one_chip):
+    """bm=3 breaks the (8, 128) block rule: the legality rule, the cost model
+    and the resolution pipeline all reject it; the compiler agrees."""
+    inst = KernelInstance.make("matmul", M=256, N=D, K=D)
+    bad = Schedule.make("matmul", {"M": 3, "N": 256, "K": 256})
+    cs = concretize(bad, inst)   # shape-valid: 3 is a maskable row tile
+    with pytest.raises(ScheduleInvalid, match="multiple of 8"):
+        legality.check(cs, TPU_V5E)
+    assert not measure(inst, bad).valid
+    pipe = ResolutionPipeline([StaticMapStage({inst.workload_key(): bad}),
+                               DefaultStage()])
+    res = pipe.resolve(inst)
+    assert res.tier == "default" and pipe.stats()["rejected_illegal"] == 1
+    legality.check(res.concrete, TPU_V5E)
+    with pytest.raises(Exception, match="divisible by 8 and 128"):
+        _compile_matmul(one_chip, cs)
+
+
+@pytest.fixture(scope="module")
+def serving_instances():
+    """gemma2-2b's serving plan: 8 decode slots, prompts bucketed to 8, 256."""
+    plan = plan_serving(GEMMA, ResolutionPipeline.build(), slots=8, max_len=256,
+                        prefill_lengths=[8, 256])
+    return sorted({u.instance for u in plan.uses}, key=lambda i: i.workload_key())
+
+
+def _compile_attention(one_chip, cs):
+    p = cs.instance.p
+    b, sq, skv = p["B"], p["Q"], p["KV"]
+    # gemma2 softcaps every layer's logits; local layers add the window
+    return _compile(lambda q, k, v, off: fa.flash_attention(
+        q, k, v, cs, causal=True, window=p.get("window", 0),
+        softcap=GEMMA.attn_softcap, q_offset=off, interpret=False),
+        _sds(one_chip, (b, HQ, sq, HD)), _sds(one_chip, (b, HKV, skv, HD)),
+        _sds(one_chip, (b, HKV, skv, HD)), _sds(one_chip, (), jnp.int32))
+
+
+@pytest.mark.parametrize("class_id", [
+    "matmul", "matmul_gelu_glu", "matmul_lmhead_softcap",
+    "flash_attention_local", "flash_attention_softcap"])
+def test_tuner_schedules_for_serving_shapes_compile(one_chip, serving_instances,
+                                                    class_id):
+    """For every gemma2-2b serving instance of the class, a schedule the
+    tuner proposes either passes the rule and compiles, or is ScheduleInvalid
+    before the compiler.  Attention also compiles the smallest KV tile the
+    rule admits, which sets the lane width of the (Q, KV) score tile."""
+    insts = [i for i in serving_instances if i.class_id == class_id]
+    assert insts
+    rng = random.Random(0)
+    for inst in insts:
+        legal = []
+        for _ in range(32):
+            s = random_schedule(inst, rng)
+            try:
+                cs = concretize(s, inst)
+                legality.check(cs, TPU_V5E)
+            except ScheduleInvalid:
+                continue   # refused before any compile: a -1 bar
+            legal.append(cs)
+        assert legal, inst
+        if inst.family == "matmul":
+            softcap = GEMMA.final_softcap if "softcap" in class_id else 0.0
+            _compile_matmul(one_chip, legal[0], softcap)
+            continue
+        _compile_attention(one_chip, legal[0])
+        unit = legality.axis_units(inst, TPU_V5E)["KV"]
+        smallest = concretize(Schedule.make(
+            class_id, {"Q": legal[0].t["Q"], "KV": unit}), inst)
+        legality.check(smallest, TPU_V5E)
+        _compile_attention(one_chip, smallest)
