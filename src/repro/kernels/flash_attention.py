@@ -88,7 +88,8 @@ def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    cs: ConcreteSchedule, *, causal: bool = True,
+                    cs: ConcreteSchedule, *,
+                    class_id: str = "flash_attention_causal", causal: bool = True,
                     window: int = 0, softcap: float = 0.0,
                     q_offset: int | jax.Array = 0, scale: float | None = None,
                     interpret: bool,
@@ -132,6 +133,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     )
     out = pl.pallas_call(
         kernel,
+        name=class_id,     # the op's name in the HLO and the device trace
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,

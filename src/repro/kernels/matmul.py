@@ -213,6 +213,7 @@ def build_call(
     out_shape = jax.ShapeDtypeStruct(((groups,) if g else ()) + (m, n_out), out_dtype)
     return pl.pallas_call(
         _squeeze_lead(kernel),
+        name=class_id,     # the op's name in the HLO and the device trace
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,

@@ -285,9 +285,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     inst = _instance(class_id, q.dtype, Q=sq, KV=k.shape[2], H=hq, D=d, B=b,
                      window=window)
     provider = _resolve(provider)
-    return _fa.flash_attention(q, k, v, provider.get(inst), causal=causal,
-                               window=window, softcap=softcap, q_offset=q_offset,
-                               **_kernel_kw(provider))
+    return _fa.flash_attention(q, k, v, provider.get(inst), class_id=class_id,
+                               causal=causal, window=window, softcap=softcap,
+                               q_offset=q_offset, **_kernel_kw(provider))
 
 
 # ---------------------------------------------------------------------------

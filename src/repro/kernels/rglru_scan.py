@@ -76,6 +76,7 @@ def rglru_scan(x: jax.Array, a: jax.Array, state: jax.Array,
     ]
     y, h_out = pl.pallas_call(
         functools.partial(_kernel, ct=ct, t_trips=grid[2], out_dtype=x.dtype),
+        name="rglru_scan",     # the op's name in the HLO and the device trace
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,

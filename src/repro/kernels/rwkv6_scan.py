@@ -103,6 +103,7 @@ def rwkv6_scan(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
     y, s_out = pl.pallas_call(
         functools.partial(_kernel, ct=ct, d=d, t_trips=grid[1],
                           out_dtype=r.dtype),
+        name="rwkv6_scan",     # the op's name in the HLO and the device trace
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,

@@ -28,9 +28,15 @@ chip never silently serve another); ``--tuning-donor-target`` optionally
 draws transfer donors from a different chip's namespace (explicit
 cross-target serving, re-validated under ``--target``'s spec).
 
-``--trace-out trace.json`` records wall-clock spans around the real jitted
-prefill/decode steps plus resolution/replan events (Perfetto-loadable;
-DESIGN.md §10); ``--metrics-out`` dumps the resolution metrics registry.
+``--trace-out trace.json`` records the engine's wall-clock spans — each
+prefill and decode step from its input upload to the host read of its
+tokens, split into prepare / dispatch / read / commit, and one async span
+per request; a step that compiled carries ``compiled=True``, so a slow
+step can be told from a recompile — plus resolution/replan events
+(Perfetto-loadable; DESIGN.md §10).  The same spans are entered as ``jax.profiler`` annotations
+(``engine.decode_step.read`` …), so a JAX profile taken around the run
+shows them on its own clock beside the device's operations.
+``--metrics-out`` dumps the resolution metrics registry.
 """
 from __future__ import annotations
 
@@ -100,8 +106,11 @@ def main(argv=None) -> dict:
                          "traffic generator): runs are reproducible per seed "
                          "but vary across seeds")
     ap.add_argument("--trace-out", default="",
-                    help="write a Perfetto-loadable Chrome trace (wall-clock "
-                         "spans around the real jitted prefill/decode steps)")
+                    help="write a Perfetto-loadable Chrome trace: wall-clock "
+                         "spans of each prefill and decode step from input "
+                         "upload to the host read of its tokens, with their "
+                         "prepare/dispatch/read/commit parts, and one span "
+                         "per request")
     ap.add_argument("--metrics-out", default="",
                     help="write the engine's resolution metrics as JSON")
     args = ap.parse_args(argv)
@@ -134,7 +143,8 @@ def main(argv=None) -> dict:
         from repro.obs import Tracer
 
         # A standalone engine has no virtual clock: spans are wall-clock
-        # around the real jitted steps (engine.trace_compute default).
+        # around the real work (engine.trace_compute default), and a
+        # wall-clock tracer also enters them as profiler annotations.
         tracer = Tracer()
         engine.tracer = tracer
         provider.pipeline.tracer = tracer

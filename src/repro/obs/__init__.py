@@ -14,7 +14,15 @@ from .metrics import (
     default_registry,
     percentile,
 )
-from .tracer import NULL_TRACER, Event, NullTracer, Span, Tracer
+from .tracer import (
+    ANNOTATION_TRACER,
+    NULL_TRACER,
+    AnnotationTracer,
+    Event,
+    NullTracer,
+    Span,
+    Tracer,
+)
 from .export import (
     chrome_trace,
     load_records,
@@ -30,7 +38,8 @@ from . import profiler
 __all__ = [
     "Counter", "CounterGroup", "Gauge", "Histogram", "MetricsRegistry",
     "default_registry", "percentile",
-    "NULL_TRACER", "Event", "NullTracer", "Span", "Tracer",
+    "ANNOTATION_TRACER", "AnnotationTracer", "NULL_TRACER", "Event",
+    "NullTracer", "Span", "Tracer",
     "chrome_trace", "load_records", "read_jsonl", "write_chrome_trace",
     "write_jsonl", "report", "profiler",
     "KINDS", "SLO", "SLOMonitor", "SLOStatus", "default_slos",

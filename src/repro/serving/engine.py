@@ -31,16 +31,33 @@ This is the TPU-idiomatic shape of continuous batching for fixed-size
 caches; ring buffers (windowed layers) and recurrent states come from the
 model substrate unchanged.
 
-**Retirement path**: :class:`~repro.serving.paged.PagedServingEngine`
-supersedes this engine for LM serving — iteration-level admission, a paged
-KV pool, and chunked (padding-free) prefill remove the two structural
-costs measured here (power-of-two prefill padding waste and prefill
-head-of-line blocking; see ``benchmarks/bench_paged.py``).  The slot engine
-remains the baseline the paged bench compares against, the reference
-semantics for the equivalence tests, and the fallback for families the
-paged path does not cover (audio encoder-decoder, vision-prefixed
-prompts).  New serving features should land in the paged engine; this
-engine is frozen apart from bug fixes.
+**Tracing.**  With a :class:`~repro.obs.Tracer` bound to ``tracer`` (and
+``trace_compute`` left on), each engine call is one span that ends after
+the host read of its tokens, so it covers the device's work:
+``prefill`` (children ``prefill.prepare`` — padded tokens and their upload,
+``prefill.dispatch`` — until the jitted call returns, ``prefill.read`` —
+the argmax and its transfer to the host, ``prefill.splice`` — dispatch of
+the cache splice) and ``decode_step`` (``decode_step.replan`` when a plan
+is swapped, ``.prepare``, ``.dispatch``, ``.read``, ``.commit`` — tokens
+appended and slots freed).  Each request is an async span (category
+``request``, id ``uid``) from admission to finish.  A call that grew a
+jitted function's cache (a compile) is marked ``compiled=True`` on its
+span, so a slow step in the trace can be told from a recompile.  The
+default, :data:`~repro.obs.ANNOTATION_TRACER`, records nothing and enters
+each region as a ``jax.profiler`` annotation named ``engine.<span>``
+(``engine.decode_step.read`` …), so a JAX profile taken around the engine
+holds its regions on the profiler's clock, and without a profile costs
+under a microsecond a region; with it, or with
+:data:`~repro.obs.NULL_TRACER`, the path does the same device work,
+transfers and syncs as with none.
+
+The slot engine is what the on-chip benchmark (``bench/``) serves.
+:class:`~repro.serving.paged.PagedServingEngine` adds iteration-level
+admission, a paged KV pool and chunked (padding-free) prefill, so far
+measured on the virtual clock only (``benchmarks/bench_paged.py``); the
+slot engine is also the reference semantics for the paged engine's
+equivalence tests and the path for families the paged engine does not
+cover (audio encoder-decoder, vision-prefixed prompts).
 """
 from __future__ import annotations
 
@@ -53,7 +70,7 @@ import numpy as np
 
 from repro.core.resolution import ExecutionPlan, plan_serving
 from repro.models.build import Model
-from repro.obs import NULL_TRACER
+from repro.obs import ANNOTATION_TRACER, NULL_TRACER
 
 
 class SlotsFull(RuntimeError):
@@ -98,6 +115,7 @@ class ServingEngine:
         self.active: dict[int, Request] = {}
         self.last_logits = None   # (slots, vocab) from the latest decode step
         self._uid = 0
+        self._admitted_at: dict[int, float] = {}   # uid -> span start, while tracing
 
         cfg = model.cfg
         kinds = set(cfg.layer_kinds)
@@ -114,11 +132,10 @@ class ServingEngine:
         self.prefill_padded_tokens = 0
 
         # Observability: the owner (fleet / launch driver) rebinds these
-        # after construction; the no-op default keeps the hot path at one
-        # attribute check.  trace_compute gates wall-clock spans around the
-        # jitted calls — fleets disable it (their tracer runs on the virtual
-        # clock, where a jitted call is zero-width).
-        self.tracer = NULL_TRACER
+        # after construction.  trace_compute gates the engine's own spans —
+        # fleets disable it (their tracer runs on the virtual clock, where a
+        # jitted call is zero-width, and the replica records the spans).
+        self.tracer = ANNOTATION_TRACER
         self.trace_track = "engine"
         self.trace_compute = True
 
@@ -230,33 +247,64 @@ class ServingEngine:
         self._prefill_lengths.add(pad)
         self.prefill_true_tokens += n
         self.prefill_padded_tokens += pad
-        toks = req.prompt + [0] * (pad - n)
-        batch = {"tokens": jnp.asarray([toks], jnp.int32)}
-        for k, v in self.extras.items():
-            batch[k] = v[None] if v.ndim == 2 else v  # (1, ..., D) stub inputs
-        if self.tracer.enabled and self.trace_compute:
-            with self.tracer.span("prefill", self.trace_track,
-                                  uid=req.uid, true_len=n, bucket=pad):
-                logits, cache1 = self._prefill(self.params, batch,
-                                               jnp.asarray(n, jnp.int32))
-        else:
-            logits, cache1 = self._prefill(self.params, batch,
-                                           jnp.asarray(n, jnp.int32))
-        # np.asarray forces the single host transfer here; int(jnp.argmax(...))
-        # would add a second device sync for the scalar read.
-        tok = int(np.asarray(jnp.argmax(logits[0])))
-        req.generated.append(tok)
-        if max_new_tokens <= 0 or (eos_id is not None and tok == eos_id) or \
-                len(req.generated) >= max_new_tokens:
-            # The prefill token is the whole response: the slot stays free
-            # (its cache rows are overwritten by the next admission).
-            req.done = True
-            return req
-        self.cache = jax.tree_util.tree_map(
-            lambda full, one: _splice_slot(full, one, slot), self.cache, cache1
-        )
-        self.active[slot] = req
+        tracer, track = self._span_tracer(), self.trace_track
+        with tracer.span("prefill", track, uid=req.uid, true_len=n,
+                         bucket=pad, slot=slot) as span:
+            if tracer.enabled:
+                self._admitted_at[req.uid] = tracer.now()
+            with tracer.span("prefill.prepare", track):
+                toks = req.prompt + [0] * (pad - n)
+                batch = {"tokens": jnp.asarray([toks], jnp.int32)}
+                for k, v in self.extras.items():
+                    batch[k] = v[None] if v.ndim == 2 else v  # (1, ..., D) stub inputs
+                true_len = jnp.asarray(n, jnp.int32)
+            with tracer.span("prefill.dispatch", track):
+                logits, cache1 = self._call(tracer, span, self._prefill,
+                                            self.params, batch, true_len)
+            with tracer.span("prefill.read", track):
+                # np.asarray forces the single host transfer here;
+                # int(jnp.argmax(...)) would add a second device sync.
+                tok = int(np.asarray(jnp.argmax(logits[0])))
+            req.generated.append(tok)
+            if max_new_tokens <= 0 or (eos_id is not None and tok == eos_id) or \
+                    len(req.generated) >= max_new_tokens:
+                # The prefill token is the whole response: the slot stays
+                # free (its cache rows are overwritten by the next admission).
+                req.done = True
+            else:
+                with tracer.span("prefill.splice", track):
+                    self.cache = jax.tree_util.tree_map(
+                        lambda full, one: _splice_slot(full, one, slot),
+                        self.cache, cache1)
+                self.active[slot] = req
+        if req.done:
+            self._finished(tracer, req)
         return req
+
+    def _span_tracer(self):
+        """Where the engine's own spans go: the bound tracer when it times
+        real work, else nowhere."""
+        return self.tracer if self.trace_compute else NULL_TRACER
+
+    def _call(self, tracer, span, fn, *args):
+        """Call a jitted entry point.  While tracing, a call that grew the
+        function's cache (a compile, or a load from the persistent cache)
+        marks ``span`` ``compiled=True``."""
+        if not tracer.enabled:
+            return fn(*args)
+        before = fn._cache_size()
+        out = fn(*args)
+        if fn._cache_size() != before:
+            span.set(compiled=True)
+        return out
+
+    def _finished(self, tracer, req: Request) -> None:
+        """Close the request's async span, if its admission was traced."""
+        t0 = self._admitted_at.pop(req.uid, None)
+        if t0 is not None and tracer.enabled:
+            tracer.add_async_span("request", self.trace_track, t0, tracer.now(),
+                                  cat="request", id=req.uid,
+                                  tokens=len(req.generated))
 
     # -- decode ----------------------------------------------------------------
     def _maybe_replan(self) -> None:
@@ -265,9 +313,7 @@ class ServingEngine:
         Only ever called at a step boundary: a plan (and its traces) is
         immutable for the duration of one decode step.
         """
-        if self.plan is None or self.provider is None:
-            return
-        if self.provider.pipeline.generation() == self.plan.generation:
+        if not self._plan_stale():
             return
         self.plan = self.plan.refresh(self.provider.pipeline)
         self.provider.plan = self.plan
@@ -277,6 +323,10 @@ class ServingEngine:
             self.tracer.event("replan", self.trace_track,
                               generation=self.plan.generation,
                               replans=self.replans)
+
+    def _plan_stale(self) -> bool:
+        return (self.plan is not None and self.provider is not None
+                and self.provider.pipeline.generation() != self.plan.generation)
 
     def refresh_plan(self) -> bool:
         """Adopt any newer published schedule generation *now* — the same
@@ -288,36 +338,45 @@ class ServingEngine:
 
     def step(self) -> list[Request]:
         """One batched decode step for all active slots; returns finished."""
-        self._maybe_replan()
         if not self.active:
+            self._maybe_replan()
             return []
+        tracer, track = self._span_tracer(), self.trace_track
         self._steps += 1
-        if self.plan is not None and (
-                not self.plan_history
-                or self.plan_history[-1][1] != self.plan.generation):
-            self.plan_history.append((self._steps, self.plan.generation))
-        toks = np.zeros(self.slots, np.int32)
-        for slot, req in self.active.items():
-            toks[slot] = req.generated[-1]
-        if self.tracer.enabled and self.trace_compute:
-            with self.tracer.span("decode_step", self.trace_track,
-                                  active=len(self.active)):
-                logits, self.cache = self._decode(self.params, self.cache,
-                                                  jnp.asarray(toks))
-        else:
-            logits, self.cache = self._decode(self.params, self.cache,
-                                              jnp.asarray(toks))
-        self.last_logits = logits
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        finished = []
-        for slot, req in list(self.active.items()):
-            tok = int(nxt[slot])
-            req.generated.append(tok)
-            if (req.eos_id is not None and tok == req.eos_id) or \
-                    len(req.generated) >= req.max_new_tokens:
-                req.done = True
-                finished.append(req)
-                del self.active[slot]
+        attrs = {}
+        if tracer.enabled:
+            attrs = {"step": self._steps, "active": len(self.active),
+                     "uids": [r.uid for r in self.active.values()]}
+        with tracer.span("decode_step", track, **attrs) as span:
+            if self._plan_stale():
+                with tracer.span("decode_step.replan", track):
+                    self._maybe_replan()
+            if self.plan is not None and (
+                    not self.plan_history
+                    or self.plan_history[-1][1] != self.plan.generation):
+                self.plan_history.append((self._steps, self.plan.generation))
+            with tracer.span("decode_step.prepare", track):
+                toks = np.zeros(self.slots, np.int32)
+                for slot, req in self.active.items():
+                    toks[slot] = req.generated[-1]
+                toks = jnp.asarray(toks)
+            with tracer.span("decode_step.dispatch", track):
+                logits, self.cache = self._call(tracer, span, self._decode,
+                                                self.params, self.cache, toks)
+                self.last_logits = logits
+            with tracer.span("decode_step.read", track):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            with tracer.span("decode_step.commit", track):
+                finished = []
+                for slot, req in list(self.active.items()):
+                    tok = int(nxt[slot])
+                    req.generated.append(tok)
+                    if (req.eos_id is not None and tok == req.eos_id) or \
+                            len(req.generated) >= req.max_new_tokens:
+                        req.done = True
+                        finished.append(req)
+                        del self.active[slot]
+                        self._finished(tracer, req)
         return finished
 
     def run_to_completion(self, max_steps: int = 512) -> None:

@@ -222,3 +222,38 @@ def test_tuner_schedules_for_serving_shapes_compile(one_chip, serving_instances,
             class_id, {"Q": legal[0].t["Q"], "KV": unit}), inst)
         legality.check(smallest, TPU_V5E)
         _compile_attention(one_chip, smallest)
+
+
+def test_kernels_carry_their_class_id_as_their_hlo_op_name(one_chip):
+    """Inside a layer scan, as the models run them, a matmul and a flash
+    attention call are named by their class in the compiled HLO, the name
+    the TPU's op line in a profile gives them (not ``closed_call``)."""
+    import re
+
+    mm = _matmul_cs("matmul_bias_gelu", 256, 1024, 512,
+                    {"M": 128, "N": 256, "K": 256})
+    inst = KernelInstance.make("flash_attention_causal", Q=256, KV=256, H=8,
+                               D=HD, B=1)
+    fa_cs = concretize(Schedule.make("flash_attention_causal",
+                                     {"Q": 128, "KV": 128}), inst)
+    legality.check(fa_cs, TPU_V5E)
+
+    def layers(x, w, b, q, k, v):
+        def body(carry, _):
+            y = mk.matmul(carry, w, mm, class_id="matmul_bias_gelu", bias=b,
+                          interpret=False)
+            o = fa.flash_attention(q, k, v, fa_cs,
+                                   class_id="flash_attention_causal",
+                                   interpret=False)
+            return carry + (y[:, :512] + o.reshape(256, -1)[:, :512]).astype(
+                carry.dtype), None
+        return jax.lax.scan(body, x, None, length=2)[0]
+
+    text = _compile(layers, _sds(one_chip, (256, 512)), _sds(one_chip, (512, 1024)),
+                    _sds(one_chip, (1024,)), _sds(one_chip, (1, 8, 256, HD)),
+                    _sds(one_chip, (1, 2, 256, HD)),
+                    _sds(one_chip, (1, 2, 256, HD))).as_text()
+    names = {re.sub(r"[.\d]+$", "", m) for m in re.findall(
+        r"^\s*(?:ROOT )?%([\w.-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text, re.M)}
+    assert names == {"matmul_bias_gelu", "flash_attention_causal"}
