@@ -15,6 +15,12 @@ The kernel realizes a :class:`repro.core.schedule.ConcreteSchedule` on TPU:
 * epilogues (bias/gelu/glu/residual/softcap) are applied on the final
   reduction step, inside the kernel.
 
+The weight operand is always a stack (L, K, N) with the layer index as a
+scalar-prefetch operand, so a layer scan hands the kernel the whole stacked
+parameter and its blocks are read in place, never sliced out into a copy
+first (a custom call cannot fuse a slice of its operand).  The schedule is
+the same at any layer: the blocks fetched are the same bytes at another base.
+
 GLU epilogues use *chunk-interleaved* packing — columns alternate (gate, up)
 in chunks of ``glu_chunk`` (one vreg's 128 lanes) — so one N-block holds
 complete pairs and can emit its (bm, bn/2) output block independently.
@@ -68,13 +74,15 @@ def _epilogue_fn(class_id: str, softcap: float,
     return f
 
 
-def _kernel(x_ref, w_ref, *rest, class_id: str, softcap: float, k_pos: int,
-            k_trips: int, use_scratch: bool, has_bias: bool, has_residual: bool,
-            chunk: int, out_dtype):
+def _kernel(layer_ref, x_ref, w_ref, *rest, class_id: str, softcap: float,
+            k_pos: int, k_trips: int, use_scratch: bool, has_bias: bool,
+            has_residual: bool, chunk: int, out_dtype):
     """Kernel body shared by all matmul classes.
 
+    ``layer_ref`` (scalar prefetch) is read only by the weight's index map.
     rest = (*optional bias_ref, *optional residual_ref, o_ref, *optional acc_ref)
     """
+    del layer_ref
     i = 0
     bias_ref = rest[i] if has_bias else None
     i += int(has_bias)
@@ -135,10 +143,17 @@ def build_call(
     interpret: bool,
     vmem_limit_bytes: int,
 ):
-    """Build a pallas_call for x:(M,K) @ w:(K,N) (+epilogue inputs) -> out.
+    """Build a pallas_call for (layer, x:(M,K), w:(L,K,N), +epilogue inputs)
+    -> x @ w[layer].
 
-    ``groups`` > 0 builds the grouped (MoE) variant: x:(E,M,K), w:(E,K,N).
-    Shape-changing (GLU) epilogues emit N//2 columns.
+    The weight is a stack and ``layer`` a (1,) int32 scalar-prefetch operand:
+    the weight's index map picks that layer's (bk, bn) blocks, so the DMA
+    reads them in place from the stack with no sliced copy in between.  A
+    plain (K,N) weight is a stack of one at layer 0.
+
+    ``groups`` > 0 builds the grouped (MoE) variant: x:(E,M,K), w:(E,K,N),
+    the leading weight axis indexed by the expert grid axis (``layer`` is
+    unused).  Shape-changing (GLU) epilogues emit N//2 columns.
     """
     bm, bn, bk = cs.t["M"], cs.t["N"], cs.t["K"]
     bm, bn, bk = min(bm, m), min(bn, n), min(bk, k)
@@ -155,23 +170,25 @@ def build_call(
     g = int(groups > 0)  # leading expert grid dim for grouped matmul
     grid = ((groups,) if g else ()) + tuple(trips[a] for a in order)
 
-    def idx(*axes):
-        def f(*pids):
+    def idx(*axes, weight: bool = False):
+        # index maps take the grid ids, then the scalar-prefetch layer ref
+        def f(*args):
+            *pids, layer = args
             base = {a: pids[g + pos[a]] for a in ("M", "N", "K")}
-            lead = (pids[0],) if g else ()
+            lead = (pids[0],) if g else (layer[0],) if weight else ()
             return lead + tuple(base[a] for a in axes)
 
         return f
 
-    lead_blk = (1,) if g else ()
+    lead_blk = (None,) if g else ()   # None: squeezed out of the kernel's refs
     in_specs = [
         pl.BlockSpec(lead_blk + (bm, bk), idx("M", "K")),
-        pl.BlockSpec(lead_blk + (bk, bn), idx("K", "N")),
+        pl.BlockSpec((None, bk, bn), idx("K", "N", weight=True)),
     ]
     n_out = n // 2 if shape_changing else n
     bn_out = bn // 2 if shape_changing else bn
     if has_bias:
-        in_specs.append(pl.BlockSpec((1, bn), lambda *p: (0, p[g + pos["N"]])))
+        in_specs.append(pl.BlockSpec((1, bn), lambda *a: (0, a[g + pos["N"]])))
     if has_residual:
         in_specs.append(pl.BlockSpec(lead_blk + (bm, bn_out), idx("M", "N")))
 
@@ -190,55 +207,45 @@ def build_call(
         out_dtype=out_dtype,
     )
 
-    def _squeeze_lead(body):
-        # grouped blocks carry a leading length-1 expert dim; strip it inside
-        if not g:
-            return body
-
-        def wrapped(x_ref, w_ref, *rest):
-            refs = [x_ref.at[0], w_ref.at[0]]
-            i = 0
-            if has_bias:
-                refs.append(rest[i])
-                i += 1
-            if has_residual:
-                refs.append(rest[i].at[0])
-                i += 1
-            refs.append(rest[i].at[0])  # o_ref
-            refs.extend(rest[i + 1:])   # scratch
-            return body(*refs)
-
-        return wrapped
-
     out_shape = jax.ShapeDtypeStruct(((groups,) if g else ()) + (m, n_out), out_dtype)
     return pl.pallas_call(
-        _squeeze_lead(kernel),
+        kernel,
         name=class_id,     # the op's name in the HLO and the device trace
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=([pltpu.VMEM((bm, bn), jnp.float32)]
+                            if use_scratch else []),
+        ),
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)] if use_scratch else [],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )
 
 
+def _layer_operand(layer) -> jax.Array:
+    return jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
+
+
 def matmul(x: jax.Array, w: jax.Array, cs: ConcreteSchedule, *,
-           class_id: str = "matmul", bias: jax.Array | None = None,
-           residual: jax.Array | None = None, softcap: float = 0.0,
-           interpret: bool,
+           layer: jax.Array | int = 0, class_id: str = "matmul",
+           bias: jax.Array | None = None, residual: jax.Array | None = None,
+           softcap: float = 0.0, interpret: bool,
            vmem_limit_bytes: int = TPU_V5E.vmem_capacity) -> jax.Array:
-    """Run the kernel: x (M,K) @ w (K,N) with fused epilogue."""
+    """Run the kernel: x (M,K) @ w (K,N) with fused epilogue.  ``w`` may be a
+    stack (L,K,N), read in place at ``layer`` (an int or a traced scalar)."""
     m, k = x.shape
-    n = w.shape[1]
+    stack = w[None] if w.ndim == 2 else w   # a free bitcast
+    n = stack.shape[2]
     call = build_call(
         m, n, k, cs, class_id=class_id, softcap=softcap,
         has_bias=bias is not None, has_residual=residual is not None,
         out_dtype=x.dtype, interpret=interpret,
         vmem_limit_bytes=vmem_limit_bytes,
     )
-    args = [x, w]
+    args = [_layer_operand(layer), x, stack]
     if bias is not None:
         args.append(bias.reshape(1, -1))
     if residual is not None:
@@ -256,4 +263,4 @@ def grouped_matmul(x: jax.Array, w: jax.Array, cs: ConcreteSchedule, *,
         m, n, k, cs, class_id=class_id, groups=e, out_dtype=x.dtype,
         interpret=interpret, vmem_limit_bytes=vmem_limit_bytes,
     )
-    return call(x, w)
+    return call(_layer_operand(0), x, w)

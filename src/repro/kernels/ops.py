@@ -29,7 +29,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import threading
-from typing import Mapping
+from typing import Mapping, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -104,6 +104,10 @@ class ScheduleProvider:
         # coverage gaps are observable.
         self._plan_served = {t: 0 for t in resolution.TIERS}
         self._plan_misses = 0
+        # Pallas matmul weight reads, counted at trace time: "in_place" adds
+        # a stack's depth for each traced call on a LayerRef (the call runs
+        # once per layer of the scan), "plain" adds 1 for a plain array.
+        self._weight_reads = {"in_place": 0, "plain": 0}
 
     @property
     def mode(self) -> str:
@@ -129,6 +133,10 @@ class ScheduleProvider:
                 self._plan_misses += 1
         return self.pipeline.resolve(instance).concrete
 
+    def count_weight_read(self, in_place: bool, layers: int) -> None:
+        with self._lock:
+            self._weight_reads["in_place" if in_place else "plain"] += layers
+
     @property
     def vmem_limit_bytes(self) -> int:
         """The target chip's VMEM budget: the legality rule sizes blocks
@@ -147,6 +155,7 @@ class ScheduleProvider:
         with self._lock:
             out["plan_served"] = dict(self._plan_served)
             out["plan_misses"] = self._plan_misses
+            out["matmul_weights"] = dict(self._weight_reads)
         out["plan_hits"] = sum(out["plan_served"].values())
         out["plan_entries"] = len(self.plan) if self.plan is not None else 0
         out["plan_generation"] = (self.plan.generation
@@ -229,16 +238,32 @@ def _kernel_kw(provider: ScheduleProvider) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def matmul(x: jax.Array, w: jax.Array, *, class_id: str = "matmul",
+class LayerRef(NamedTuple):
+    """Layer ``index`` of a stacked weight ``stack`` (L, K, N), as a layer
+    scan hands it to :func:`matmul`: the Pallas kernel reads the layer's
+    blocks in place from the stack, where a slice ``stack[index]`` would be
+    copied out first."""
+    stack: jax.Array
+    index: jax.Array
+
+
+def matmul(x: jax.Array, w: jax.Array | LayerRef, *, class_id: str = "matmul",
            bias: jax.Array | None = None, residual: jax.Array | None = None,
            softcap: float = 0.0, provider: ScheduleProvider | None = None,
            backend: str | None = None) -> jax.Array:
-    """x: (..., K) @ w: (K, N) with fused epilogue. GLU classes emit N//2."""
+    """x: (..., K) @ w: (K, N) with fused epilogue. GLU classes emit N//2.
+
+    ``w`` may be a :class:`LayerRef`; the schedule is keyed on (M, N, K)
+    alone, whichever layer is read."""
     backend = backend or _default_backend()
-    *lead, k = x.shape
-    n = w.shape[1]
+    in_place = isinstance(w, LayerRef)
     if backend == "ref":
+        if in_place:
+            w = jax.lax.dynamic_index_in_dim(w.stack, w.index, keepdims=False)
         return ref.matmul(x, w, class_id, bias=bias, residual=residual, softcap=softcap)
+    stack, layer = (w.stack, w.index) if in_place else (w, 0)
+    *lead, k = x.shape
+    n = stack.shape[-1]
     m = 1
     for s in lead:
         m *= s
@@ -246,8 +271,10 @@ def matmul(x: jax.Array, w: jax.Array, *, class_id: str = "matmul",
     res2 = residual.reshape(m, -1) if residual is not None else None
     inst = _instance(class_id, x.dtype, M=m, N=n, K=k)
     provider = _resolve(provider)
-    y = _mm.matmul(x2, w, provider.get(inst), class_id=class_id, bias=bias,
-                   residual=res2, softcap=softcap, **_kernel_kw(provider))
+    provider.count_weight_read(in_place, stack.shape[0] if in_place else 1)
+    y = _mm.matmul(x2, stack, provider.get(inst), layer=layer,
+                   class_id=class_id, bias=bias, residual=res2,
+                   softcap=softcap, **_kernel_kw(provider))
     return y.reshape(*lead, y.shape[-1])
 
 

@@ -6,6 +6,9 @@ groups* — params are stacked with leading dim = full pattern repeats — plus
 explicit tail layers for the remainder (griffin's 26 = 8×3 + 2).  Scan keeps
 the HLO (and compile time) independent of depth; the group body is wrapped
 in ``jax.checkpoint`` for training (save-residual-boundaries remat policy).
+The serving scans (prefill, chunked prefill, verify, decode) scan over the
+layer index instead and close over the stacks, so the matmul kernel reads
+each layer's projections in place rather than a sliced copy of them.
 
 Three entry points (built per-config by :mod:`repro.models.build`):
   forward(params, batch)          — full-sequence logits (+aux), train/eval
@@ -128,6 +131,26 @@ def _pattern_split(cfg: ArchConfig) -> tuple[tuple[str, ...], int, tuple[str, ..
     return pat, reps, pat[:rem]
 
 
+def _layer_params(groups: dict, l: jax.Array) -> dict:
+    """Layer ``l`` of the stacked group params, for the serving scans.  The
+    dense projections of attention and MLP blocks (the 3-D leaves of every
+    ``attn`` / ``mlp`` group) become :class:`~repro.kernels.ops.LayerRef`,
+    which the Pallas matmul reads in place from the stack; the rest (norm
+    scales, biases, recurrent and MoE leaves) is sliced: small, or read by
+    code other than the matmul."""
+    def take(leaf):
+        return jax.lax.dynamic_index_in_dim(leaf, l, keepdims=False)
+
+    def block(stacked: dict) -> dict:
+        return {name: ({k: ops.LayerRef(w, l) if w.ndim == 3 else take(w)
+                        for k, w in sub.items()}
+                       if name in ("attn", "mlp")
+                       else jax.tree_util.tree_map(take, sub))
+                for name, sub in stacked.items()}
+
+    return {i: block(stacked) for i, stacked in groups.items()}
+
+
 def init_params(key: jax.Array, cfg: ArchConfig) -> dict:
     pat, reps, tail = _pattern_split(cfg)
     keys = jax.random.split(key, 8)
@@ -206,7 +229,15 @@ def _stack_pass(params: dict, cfg: ArchConfig, h: jax.Array, *,
                 lambda c, lp: body(c, (lp, None)), (h, aux), params["groups"]
             )
         else:
-            (h, aux), ys = jax.lax.scan(body, (h, aux), (params["groups"], caches["groups"]))
+            # the stacked weights are closed over, not scanned: each layer's
+            # projections are read in place (_layer_params)
+            def serve_body(carry, xs):
+                l, layer_cache = xs
+                return body(carry, (_layer_params(params["groups"], l),
+                                    layer_cache))
+
+            (h, aux), ys = jax.lax.scan(serve_body, (h, aux),
+                                        (jnp.arange(reps), caches["groups"]))
             new_caches["groups"] = ys
     for j, kind in enumerate(tail):
         c_in = caches["tail"][j] if caches is not None else None
@@ -381,7 +412,8 @@ def decode_step(params: dict, cfg: ArchConfig, cache: dict, tokens: jax.Array, *
 
     def group_body(carry, xs):
         hh = carry
-        layer_params, layer_cache = xs
+        l, layer_cache = xs
+        layer_params = _layer_params(params["groups"], l)
         new_cache = {}
         for i, kind in enumerate(pat):
             hh, c_out, _ = apply_block(layer_params[str(i)], cfg, kind, hh,
@@ -392,7 +424,7 @@ def decode_step(params: dict, cfg: ArchConfig, cache: dict, tokens: jax.Array, *
 
     new_cache = {"groups": {}, "tail": [], "t": pos + 1}
     if reps > 0:
-        h, ys = jax.lax.scan(group_body, h, (params["groups"], cache["groups"]))
+        h, ys = jax.lax.scan(group_body, h, (jnp.arange(reps), cache["groups"]))
         new_cache["groups"] = ys
     for j, kind in enumerate(tail):
         h, c_out, _ = apply_block(params["tail"][j], cfg, kind, h,

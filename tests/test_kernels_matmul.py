@@ -136,3 +136,32 @@ def test_glu_forces_scratch_on_bad_order():
     y = mk.matmul(x, w, cs, class_id="matmul_silu_glu", interpret=True)
     np.testing.assert_allclose(y, ref.matmul(x, w, "matmul_silu_glu"),
                                rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("m", [8, 200])
+@pytest.mark.parametrize("class_id,n,tn", [
+    ("matmul", 64, 32),
+    ("matmul_bias", 64, 32),
+    ("matmul_bias_gelu", 64, 32),
+    ("matmul_silu_glu", 512, 256),
+])
+def test_layer_indexed_matmul_reads_the_stack_in_place(class_id, n, tn, m):
+    """The weight as a whole (L=3, K, N) stack with a traced layer index
+    equals the kernel on the sliced w[l], at every layer: three K blocks and,
+    at M=200, a partial last M tile."""
+    k, layers = 48, 3
+    r = np.random.default_rng(m + n)
+    x = jnp.asarray(r.normal(size=(m, k)), jnp.float32)
+    stack = jnp.asarray(r.normal(size=(layers, k, n)), jnp.float32)
+    bias = (jnp.asarray(r.normal(size=(n,)), jnp.float32)
+            if "bias" in class_id else None)
+    inst = KernelInstance.make(class_id, M=m, N=n, K=k, dtype="float32")
+    cs = concretize(Schedule.make(class_id, {"M": 64, "N": tn, "K": 16}), inst)
+    assert cs.g["K"] == 3
+    in_place = jax.jit(lambda x, w, l: mk.matmul(
+        x, w, cs, layer=l, class_id=class_id, bias=bias, interpret=True))
+    for layer in range(layers):
+        got = in_place(x, stack, layer)
+        want = mk.matmul(x, stack[layer], cs, class_id=class_id, bias=bias,
+                         interpret=True)
+        np.testing.assert_array_equal(got, want)

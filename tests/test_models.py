@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCH_IDS, get_arch, reduced
+from repro.kernels import ops
 from repro.launch import steps as steps_mod
 from repro.models import build_model
 from repro.models.common import count_params
@@ -104,3 +105,86 @@ def test_long_context_ring_cache_memory(rng):
     k_leaves = [l for p, l in jax.tree_util.tree_flatten_with_path(cache)[0]
                 if "'k'" in jax.tree_util.keystr(p)]
     assert k_leaves and all(l.shape[-2] == cfg.window for l in k_leaves)
+
+
+# ---------------------------------------------------------------------------
+# Serving scans read each layer's weights in place from the stacks
+# ---------------------------------------------------------------------------
+
+
+def _lm_with_biases(arch):
+    cfg = reduced(get_arch(arch))
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(2))
+    if cfg.mlp_bias:   # zero at init: give the bias epilogues something to add
+        r = np.random.default_rng(3)
+        mlp = params["groups"]["0"]["mlp"]
+        for name in ("b_in", "b_out"):
+            mlp[name] = jnp.asarray(r.normal(size=mlp[name].shape) * 0.5,
+                                    mlp[name].dtype)
+    return cfg, model, params
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in its params."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+@pytest.mark.parametrize("arch", ["minitron-4b", "starcoder2-7b",
+                                  "recurrentgemma-2b", "mixtral-8x22b"])
+def test_pallas_serving_matches_ref_over_decode_steps(arch, rng):
+    """Prefill and three decode steps through the Pallas kernels (interpret
+    mode), which read every layer's attention and MLP projections in place
+    from the stacks, match the ref backend, which slices them: dense with and
+    without MLP biases, recurrent blocks' MLPs, and MoE blocks' attention."""
+    cfg, model, params = _lm_with_biases(arch)
+    b, s, steps = 2, 10, 3
+    toks = jnp.asarray(rng.integers(1, cfg.vocab_size, (b, s + steps)), jnp.int32)
+
+    def run(backend):
+        with ops.use_backend(backend):   # fresh jits: the backend is read at trace
+            prefill = jax.jit(lambda p, t: model.prefill(
+                p, {"tokens": t}, max_len=s + steps))
+            decode = jax.jit(lambda p, c, t: model.decode_step(p, c, t))
+            logits, cache = prefill(params, toks[:, :s])
+            out = [logits]
+            for i in range(steps):
+                logits, cache = decode(params, cache, toks[:, s + i])
+                out.append(logits)
+        return out
+
+    for got, want in zip(run("pallas"), run("ref")):
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("arch", ["minitron-4b", "starcoder2-7b"])
+def test_decode_step_copies_no_stacked_weight(arch):
+    """The traced decode step neither slices a stacked (L, K, N) weight nor
+    scans over one: the matmuls take the stacks whole (6 per layer, counted
+    by the provider) and only the untied lm head is a plain weight."""
+    cfg, model, params = _lm_with_biases(arch)
+    cache = model.init_cache(2, 16)
+    provider = ops.ScheduleProvider()
+    with ops.use_backend("pallas"):
+        closed = jax.make_jaxpr(lambda p, c, t: model.decode_step(
+            p, c, t, provider=provider))(params, cache, jnp.zeros((2,), jnp.int32))
+    stacks = {w.shape for w in jax.tree_util.tree_leaves(params["groups"])
+              if w.ndim == 3}
+    copied = []
+    for eqn in _eqns(closed.jaxpr):
+        if eqn.primitive.name in ("dynamic_slice", "slice", "gather"):
+            operands = eqn.invars[:1]
+        elif eqn.primitive.name == "scan":   # xs follow the consts and carry
+            operands = eqn.invars[eqn.params["num_consts"] + eqn.params["num_carry"]:]
+        else:
+            continue
+        copied += [eqn for v in operands if getattr(v.aval, "shape", ()) in stacks]
+    assert not copied
+    assert provider.stats()["matmul_weights"] == {"in_place": 6 * cfg.n_layers,
+                                                  "plain": 1}

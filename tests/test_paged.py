@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_arch, reduced
+from repro.kernels.ops import use_backend
 from repro.models import build_model
 from repro.serving import (
     PagedServingEngine,
@@ -225,11 +226,7 @@ def _run_paged(model, params, prompts, *, fragment=False, mnt=5, **kw):
     return reqs, eng
 
 
-def test_paged_matches_slot_bit_exact_global_attention(small_lm):
-    """G-only arch: pages + gather/scatter + chunked prefill change nothing
-    — token streams match the slot engine's exact (unbucketed) prefill."""
-    cfg, model, params = small_lm
-    prompts = _prompts(cfg)
+def _check_paged_matches_slot(model, params, prompts):
     paged_reqs, _ = _run_paged(model, params, prompts)
 
     slot = ServingEngine(model, params, slots=len(prompts), max_len=32,
@@ -239,6 +236,22 @@ def test_paged_matches_slot_bit_exact_global_attention(small_lm):
         slot.step()
     for pr, sr in zip(paged_reqs, slot_reqs):
         assert pr.generated == sr.generated
+
+
+def test_paged_matches_slot_bit_exact_global_attention(small_lm):
+    """G-only arch: pages + gather/scatter + chunked prefill change nothing
+    — token streams match the slot engine's exact (unbucketed) prefill."""
+    cfg, model, params = small_lm
+    _check_paged_matches_slot(model, params, _prompts(cfg))
+
+
+def test_paged_matches_slot_on_pallas_kernels(small_lm):
+    """The same equivalence through the Pallas kernels (interpret mode):
+    chunked prefill, one-shot prefill and decode all read each layer's
+    weights in place from the stacks."""
+    cfg, model, params = small_lm
+    with use_backend("pallas"):
+        _check_paged_matches_slot(model, params, _prompts(cfg, lens=(3, 11)))
 
 
 def test_fragmented_pool_is_bit_exact_vs_contiguous(small_lm):

@@ -328,6 +328,47 @@ def test_provider_consults_plan_before_pipeline(tmp_path):
     assert provider.hits == 2  # the re-planning resolve + the plan-served call
 
 
+def test_in_place_weights_keep_the_serving_plan_keys():
+    """Reading each layer's weights in place from the stacks keys schedules
+    on (class, M, N, K) alone, as slicing did, never on the layer: the
+    traced decode step asks for plan_serving's decode matmuls, each a plan
+    hit, and the plan's tiers are unchanged."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import get_arch, reduced
+    from repro.core.resolution import plan_serving
+    from repro.kernels.ops import use_backend
+    from repro.models import build_model
+
+    cfg = reduced(get_arch("minitron-4b"))
+    pipe = ResolutionPipeline.build()
+    plan = plan_serving(cfg, pipe, slots=2, max_len=16, prefill_lengths=[8])
+    assert plan.tier_counts() == {"exact": 0, "transfer": 0, "static": 0,
+                                  "default": 10}
+    asked = []
+
+    class Recording(ScheduleProvider):
+        def get(self, instance):
+            asked.append(instance)
+            return super().get(instance)
+
+    provider = Recording(pipeline=pipe, plan=plan)
+    model = build_model(cfg)
+    with use_backend("pallas"):
+        jax.make_jaxpr(lambda p, c, t: model.decode_step(p, c, t, provider=provider))(
+            jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+            jax.eval_shape(lambda: model.init_cache(2, 16)),
+            jnp.zeros((2,), jnp.int32))
+    assert {(i.class_id, i.p["M"], i.p["N"], i.p["K"]) for i in asked} == {
+        ("matmul", 2, 64, 64), ("matmul", 2, 64, 128),
+        ("matmul_bias_gelu", 2, 128, 64), ("matmul_lmhead", 2, 512, 64)}
+    assert all(set(i.p) == {"M", "N", "K"} for i in asked)
+    assert ({i.workload_key() for i in asked}
+            <= {u.instance.workload_key() for u in plan.uses})
+    assert provider.stats()["plan_misses"] == 0
+
+
 # ---------------------------------------------------------------------------
 # Service generation / changed-workload notification
 # ---------------------------------------------------------------------------

@@ -12,6 +12,7 @@ from repro.core.extract import extract_kernels
 from repro.core.resolution import spec_verify_uses
 from repro.fleet import AcceptanceTracker, ServingFleet, TrafficGenerator
 from repro.fleet.traffic import load_trace, save_trace
+from repro.kernels.ops import use_backend
 from repro.models import build_model
 from repro.serving import (
     PagedServingEngine,
@@ -152,6 +153,24 @@ def test_partial_acceptance_is_bit_exact(small_lm):
     for pr, sr in zip(plain, spec):
         assert pr.generated == sr.generated
     assert 0 < eng.spec_accepted < eng.spec_proposed  # genuinely partial
+
+
+def test_partial_acceptance_is_bit_exact_on_pallas_kernels(small_lm):
+    """Partial acceptance through the Pallas kernels (interpret mode): the
+    verify step reads each layer's weights in place as decode does, and
+    reproduces plain decode token-for-token."""
+    cfg, model, params = small_lm
+    dcfg, dparams, tparams = make_self_draft(cfg, params, keep_layers=1,
+                                             damp=0.05)
+    draft = build_model(dcfg)
+    prompts = _prompts(cfg)
+    with use_backend("pallas"):
+        plain, _ = _run(model, tparams, prompts)
+        spec, eng = _run(model, tparams, prompts, draft_model=draft,
+                         draft_params=dparams, spec_k=3)
+    for pr, sr in zip(plain, spec):
+        assert pr.generated == sr.generated
+    assert 0 < eng.spec_accepted < eng.spec_proposed
 
 
 def test_spec_k0_degrades_to_plain(small_lm, drafted):

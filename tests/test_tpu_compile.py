@@ -257,3 +257,28 @@ def test_kernels_carry_their_class_id_as_their_hlo_op_name(one_chip):
         r"^\s*(?:ROOT )?%([\w.-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
         text, re.M)}
     assert names == {"matmul_bias_gelu", "flash_attention_causal"}
+
+
+@pytest.mark.parametrize("in_place", [True, False])
+def test_layer_scan_reads_stacked_weights_in_place(one_chip, in_place):
+    """A layer scan that closes over a (L, K, N) weight stack and hands the
+    matmul the layer index, as the serving scans do, compiles with no
+    dynamic-slice copying a layer out of the stack; scanning the stack as
+    the scan's input (the control) compiles to that copy."""
+    import re
+
+    cs = _matmul_cs("matmul", 8, D, D, {"M": 8, "N": 256, "K": 256})
+
+    def layers(x, stack):
+        def body(h, xs):
+            w, layer = (stack, xs) if in_place else (xs, 0)
+            return mk.matmul(h, w, cs, layer=layer, interpret=False), None
+        xs = jnp.arange(stack.shape[0]) if in_place else stack
+        return jax.lax.scan(body, x, xs)[0]
+
+    text = _compile(layers, _sds(one_chip, (8, D)),
+                    _sds(one_chip, (4, D, D))).as_text()
+    assert "tpu_custom_call" in text
+    copies = re.findall(rf"%[\w.-]*dynamic-slice[\w.-]* = bf16\[(?:1,)?{D},{D}\]",
+                        text)
+    assert bool(copies) != in_place
