@@ -60,12 +60,12 @@ def main(argv=None, *, device: dict | None = None) -> list[dict]:
             setup.engine.params = setup.weights = None
             gc.collect()
             setup.weights = setup.engine.params = jax.block_until_ready(
-                weights.make(cell.model, seed))
+                weights.make(cell.arch, cell.model, seed))
         reqs = traffic.generate(cell.traffic, seconds=args.seconds, seed=seed,
                                 vocab=cell.model["vocab_size"])
         rec = harness.run_window(setup, reqs, loop=cell.traffic["loop"],
                                  seconds=args.seconds)
-        read = check.readings(setup.weights, cell.model,
+        read = check.readings(setup.weights, cell.arch, cell.model,
                               check.sample(rec["finished"], seed),
                               control=seed in control)
         ctrl = read.pop("control", None)

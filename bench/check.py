@@ -4,15 +4,18 @@ Once the window has closed, a sample of the requests the engine finished is
 drawn from the seed, the longest among them, until it holds
 ``SAMPLE_TOKENS`` served tokens or the reference would run over
 ``SAMPLE_POSITIONS`` positions (long prompts with short answers reach the
-second first).  The plain reference (``reference.py``)
-runs once over each prompt followed by its served tokens, and the number
-compared is the widest gap, over every served token of the sample, by
-which the served token's logit lies below the reference's best logit at
-that position.  Greedy decoding serves the program's own best token, so a
-sound program reads a gap of rounding size: its bf16 logits put first a
-token that the float32 reference has within rounding of its best.
+second first).  The plain reference (the configuration's
+``bench/models/<name>.py`` with ``reference.py``) runs once over each prompt
+followed by its served tokens, and the number compared is the widest gap,
+over every served token of the sample, by which the served token's logit
+lies below the reference's best logit at that position.  Greedy decoding
+serves the program's own best token, so a sound program reads a gap of
+rounding size: its bf16 logits put first a token that the float32 reference
+has within rounding of its best.
 """
 from __future__ import annotations
+
+import functools
 
 import numpy as np
 
@@ -43,14 +46,17 @@ def sample(finished: list, seed: int) -> list:
     return picked
 
 
-def readings(weights, model: dict, picked: list, *, control: bool = False) -> dict:
-    """Widest gap of the served tokens over the picked requests.  With
-    ``control``, ``read["control"]`` holds the same readings for the tokens
-    the fp8 reference puts first, in the program's place, for ``decide``."""
-    cfg = reference.RefCfg.from_model(model)
+def readings(weights, arch, model: dict, picked: list, *,
+             control: bool = False) -> dict:
+    """Widest gap of the served tokens over the picked requests, through the
+    pass of the architecture module ``arch`` (``bench/models/<name>.py``).
+    With ``control``, ``read["control"]`` holds the same readings for the
+    tokens the fp8 reference puts first, in the program's place, for
+    ``decide``."""
+    hidden = functools.partial(arch.final_hidden, weights, arch.ref_config(model))
     served, ctrl, n = [], [], 0
     for s in picked:
-        g = reference.gaps(weights, cfg, s.prompt, s.generated, control=control)
+        g = reference.gaps(weights, hidden, s.prompt, s.generated, control=control)
         served.append(float(np.max(g["served"])))
         n += len(g["served"])
         if control:

@@ -2,12 +2,15 @@
 
 A cell (one ``workloads`` entry of ``BENCHMARK.json``) names a
 configuration, ``bench/configs/<config>.json``, and a traffic mix,
-``bench/traffic/<traffic>.json``.  The harness builds what the repo's serving
-entry (``repro.launch.serve``) builds: the model, the slot
-``ServingEngine`` with a ``ScheduleProvider`` over a ``TuningService``, under
+``bench/traffic/<traffic>.json``.  The configuration file names its
+architecture's module, ``bench/models/<reference_module>.py``
+(``arch_module``).  The harness builds what the repo's serving entry
+(``repro.launch.serve``) builds: the model, the slot ``ServingEngine`` with a
+``ScheduleProvider`` over a ``TuningService``, under
 ``use_backend("pallas")``.  Set-up, in order:
 
-1. the weights, on the device from ``--seed`` (``bench/weights.py``);
+1. the weights, on the device from ``--seed`` (``bench/weights.py``, in the
+   module's layout);
 2. the donor tuned with the cost model from the configuration's fixed
    tuning seed into a fresh registry (as ``chip_smoke.tune_and_plan``);
 3. the served plan resolved through the ``TuningService`` and its transfer
@@ -27,13 +30,16 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import importlib.util
 import json
 import os
+import re
 import shutil
+import sys
 import time
 from collections import deque
+from types import ModuleType
 
-import counts
 import traffic as traffic_mod
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
@@ -42,6 +48,7 @@ ROOT = os.path.dirname(BENCH)      # where BENCHMARK.json and its data files are
 CACHE = os.path.join(BENCH, ".cache")
 TRACE_SECONDS = 10.0     # a traced run profiles this much of the window's end
 TRIALS_PER_KERNEL = 16   # cost-model trials the donor's tuning spends per kernel
+MODULE_NAME = re.compile(r"[A-Za-z0-9_]{1,64}")
 
 
 @dataclasses.dataclass
@@ -51,6 +58,7 @@ class Cell:
     config: dict
     traffic: dict
     limits: dict | None
+    arch: ModuleType      # bench/models/<reference_module>.py
 
     @property
     def model(self) -> dict:
@@ -77,12 +85,45 @@ def load_cell(name: str) -> tuple[Cell, dict]:
     conf = next(c for c in spec["configs"] if c["name"] == w["config"])
     bench = os.path.join(root, "bench")
     limits = os.path.join(bench, "limits", f"{name}.json")
-    cell = Cell(name=name, chips=w["chips"],
-                config=_json(os.path.join(root, conf["file"])),
+    config = _json(os.path.join(root, conf["file"]))
+    cell = Cell(name=name, chips=w["chips"], config=config,
                 traffic=traffic_mod.load(os.path.join(bench, "traffic",
                                                       f"{w['traffic']}.json")),
-                limits=_json(limits) if os.path.exists(limits) else None)
+                limits=_json(limits) if os.path.exists(limits) else None,
+                arch=arch_module(config, conf["file"]))
     return cell, spec
+
+
+def arch_module(config: dict, file: str) -> ModuleType:
+    """The module ``bench/models/<name>.py`` that the configuration file
+    ``file`` names under ``reference_module``; a missing key or a name with
+    no such module is an error that names the file.
+
+    The module holds what is specific to one architecture's equations:
+    ``program_config(conf)``, the program's ``ArchConfig`` for the file,
+    checked against its ``model`` block; ``layout(m)``, the weights' leaf
+    shapes and draws in the program's parameter tree (``weights.py``);
+    ``ref_config(m)``, the reference's hashable settings, refusing what it
+    does not compute; ``final_hidden(weights, cfg, tokens, quant=None)``,
+    the plain float32 pass to the final norm, with its fp8 control
+    (``reference.gaps`` reads the head); and ``dims(m)``, the operations and
+    bytes the metrics read as ``rec["dims"]``."""
+    name = config.get("reference_module")
+    if name is None:
+        raise ValueError(f"{file}: no 'reference_module' names the "
+                         f"architecture's module in bench/models/")
+    models = os.path.join(ROOT, "bench", "models")
+    path = os.path.join(models, f"{name}.py")
+    if not (isinstance(name, str) and MODULE_NAME.fullmatch(name)
+            and os.path.isfile(path)):
+        known = sorted(f[:-3] for f in os.listdir(models) if f.endswith(".py"))
+        raise ValueError(f"{file}: unknown reference_module {name!r}; "
+                         f"bench/models/ has {known}")
+    spec = importlib.util.spec_from_file_location(f"bench_model_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod       # dataclasses look their module up there
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def cell_metrics(spec: dict, cell: str, kind: str) -> list[dict]:
@@ -93,34 +134,6 @@ def cell_metrics(spec: dict, cell: str, kind: str) -> list[dict]:
 # ---------------------------------------------------------------------------
 # The program under test
 # ---------------------------------------------------------------------------
-
-
-def arch_config(conf: dict):
-    """The program's ``ArchConfig`` for a configuration file, checked
-    against the file's ``model`` block so that the file is what runs."""
-    from repro.configs.base import get_arch
-
-    r = conf["repro"]
-    cfg = dataclasses.replace(get_arch(r["arch"]), **r["overrides"])
-    m = conf["model"]
-    want = {
-        "n_layers": m["num_hidden_layers"], "d_model": m["hidden_size"],
-        "d_ff": m["intermediate_size"], "n_heads": m["num_attention_heads"],
-        "n_kv_heads": m["num_key_value_heads"], "head_dim": m["head_dim"],
-        "vocab_size": m["vocab_size"], "rope_theta": m["rope_theta"],
-        "mlp_bias": m["use_bias"], "tie_embeddings": m["tie_word_embeddings"],
-        "dtype": m["torch_dtype"],
-        "norm": {"layer_norm": "layernorm", "rms_norm": "rmsnorm"}[m["norm_type"]],
-        "mlp_kind": {"gelu_tanh": "gelu"}[m["mlp_activation"]],
-        "family": "dense", "layer_pattern": ("G",), "window": 0, "pos": "rope",
-        "attn_softcap": 0.0, "final_softcap": 0.0, "n_experts": 0,
-        "vision_tokens": 0, "encoder_layers": 0,
-    }
-    wrong = {k: (getattr(cfg, k), v) for k, v in want.items() if getattr(cfg, k) != v}
-    if wrong:
-        raise ValueError(f"{conf['name']}: program config differs from the file "
-                         f"(program, file): {wrong}")
-    return cfg
 
 
 def tune(conf: dict, cfg, target: str, registry_dir: str):
@@ -180,7 +193,7 @@ class Setup:
     engine: object
     provider: object
     service: object
-    dims: counts.Dims
+    dims: object          # the architecture module's dims(m)
     info: dict
     spans: dict
     prev_provider: object = None
@@ -204,10 +217,10 @@ def build(cell: Cell, seed: int, target: str) -> Setup:
     from repro.serving import ServingEngine
 
     spans = {}
-    cfg = arch_config(cell.config)
+    cfg = cell.arch.program_config(cell.config)
     model = build_model(cfg)
     t = time.monotonic()
-    params = jax.block_until_ready(weights_mod.make(cell.model, seed))
+    params = jax.block_until_ready(weights_mod.make(cell.arch, cell.model, seed))
     spans["weights_s"] = time.monotonic() - t
     t = time.monotonic()
     provider, service, info = tune(cell.config, cfg, target,
@@ -225,8 +238,8 @@ def build(cell: Cell, seed: int, target: str) -> Setup:
     spans["compile_s"] = time.monotonic() - t
     info["plan_tiers"] = engine.plan.tier_counts()
     info["plan_entries"] = len(engine.plan)
-    return Setup(params, engine, provider, service,
-                 counts.Dims.from_model(cell.model), info, spans, prev_provider)
+    return Setup(params, engine, provider, service, cell.arch.dims(cell.model),
+                 info, spans, prev_provider)
 
 
 # ---------------------------------------------------------------------------

@@ -1,9 +1,10 @@
 """Decode step's share of its roofline, in %: the least time the decode
 steps that ran while the trace ran could take on the chip -- the larger of
 their operations over the bf16 peak and their least bytes (weights once,
-each active slot's live cache rows and new rows; ``counts.py``) over the
-HBM bandwidth -- over the device time of the ``jit_decode_fn`` program in
-the trace.  At these batch sizes the bytes bound the step."""
+each active slot's live cache rows and new rows; the architecture module's
+``dims``) over the HBM bandwidth -- over the device time of the
+``jit_decode_fn`` program in the trace.  At these batch sizes the bytes
+bound the step."""
 
 
 def read(rec):

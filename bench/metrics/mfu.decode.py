@@ -1,6 +1,7 @@
 """Decode share of the chip's bf16 peak, in %: operations of the active
-slots in the decode steps that ran while the trace ran (``counts.py``) over
-the device time of the ``jit_decode_fn`` program in the trace."""
+slots in the decode steps that ran while the trace ran (the module's
+``dims``) over the device time of the ``jit_decode_fn`` program in the
+trace."""
 
 
 def read(rec):

@@ -1,12 +1,12 @@
 """Causal flash attention's share of the chip's bf16 peak in prefill, in %:
-the attention operations (scores and weighted values, ``counts.Dims.
-attention_flops`` for each layer over the causal triangle of the true
-prompt tokens, as ``prefill_flops`` counts them) of the prefills that ran
-while the trace ran, over the self time, inside ``jit_prefill_fn``, of the
-ops the trace names as a flash-attention kernel class (``op_module_s`` of
-``engine_trace.py``).  The pad rows the kernel also computes count in
-its time, not in its operations.  None where the trace names no such
-class."""
+the attention operations (scores and weighted values, ``attention_flops`` of
+the architecture module's ``dims`` for each layer over the causal triangle
+of the true prompt tokens, as ``prefill_flops`` counts them) of the prefills
+that ran while the trace ran, over the self time, inside ``jit_prefill_fn``,
+of the ops the trace names as a flash-attention kernel class
+(``op_module_s`` of ``engine_trace.py``).  The pad rows the kernel also
+computes count in its time, not in its operations.  None where the trace
+names no such class."""
 import engine_trace
 
 ATTENTION = "flash_attention"

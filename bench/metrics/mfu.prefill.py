@@ -1,6 +1,7 @@
 """Prefill share of the chip's bf16 peak, in %: operations of the true
-(unpadded) prompt tokens prefilled while the trace ran (``counts.py``) over
-the device time of the ``jit_prefill_fn`` program in the trace."""
+(unpadded) prompt tokens prefilled while the trace ran (the module's
+``dims``) over the device time of the ``jit_prefill_fn`` program in the
+trace."""
 
 
 def read(rec):

@@ -13,12 +13,12 @@ import engine_trace
 
 
 def class_weights(d) -> dict[str, int]:
-    """Weight elements each matmul class reads in one decode step of a
-    dense GQA decoder with a GELU MLP (``counts.Dims``), as the program
-    assigns classes: the q, k, v and output projections run as ``matmul``,
-    the MLP's up projection as ``matmul_bias_gelu``, its down projection as
-    ``matmul_bias`` with a bias and as ``matmul`` without, the head as
-    ``matmul_lmhead``."""
+    """Weight elements each matmul class reads in one decode step of a dense
+    GQA decoder with a GELU MLP (``Dims`` of ``bench/models/dense_gqa.py``),
+    as the program assigns classes: the q, k, v and output projections run
+    as ``matmul``, the MLP's up projection as ``matmul_bias_gelu``, its down
+    projection as ``matmul_bias`` with a bias and as ``matmul`` without, the
+    head as ``matmul_lmhead``."""
     proj = (d.d_model * (d.heads + 2 * d.kv_heads) * d.head_dim
             + d.heads * d.head_dim * d.d_model)
     up = d.d_model * d.d_ff + (d.d_ff if d.mlp_bias else 0)

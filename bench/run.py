@@ -182,7 +182,8 @@ def main(argv=None, *, device: dict | None = None) -> dict:
     setup.close()
     del setup
     gc.collect()
-    read = check.readings(weights, cell.model, check.sample(finished, args.seed))
+    read = check.readings(weights, cell.arch, cell.model,
+                          check.sample(finished, args.seed))
     ok, failed, checks = check.decide(read, cell.limits)
 
     kind = "per_layer" if args.trace else "end_to_end"

@@ -4,9 +4,13 @@ dims, vocabulary 512, bf16) served through the same harness, with an
 open-loop and a closed-loop mix."""
 from __future__ import annotations
 
+import glob
 import json
 import os
+import shutil
 import sys
+
+import pytest
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REPO = os.path.dirname(BENCH)
@@ -22,7 +26,7 @@ MODEL = {"num_hidden_layers": 2, "hidden_size": 64, "intermediate_size": 128,
 DIMS = {"n_layers": 2, "d_model": 64, "d_ff": 128, "n_heads": 4, "n_kv_heads": 2,
         "head_dim": 16, "vocab_size": 512}
 CONFIG = {
-    "name": "tiny", "source": "test",
+    "name": "tiny", "source": "test", "reference_module": "dense_gqa",
     "model": MODEL,
     "repro": {"arch": "starcoder2-7b",
               "overrides": dict(DIMS, norm="layernorm", rope_theta=10000.0)},
@@ -74,10 +78,21 @@ def spec() -> dict:
         per_layer=[retarget(m) for m in real["per_layer"]])
 
 
+def arch():
+    """``bench/models/dense_gqa.py``, the tiny model's module, as the
+    harness loads it from the repo."""
+    import harness
+
+    return harness.arch_module(CONFIG, "bench_tiny.CONFIG")
+
+
 def write(root: str, *, limit: float | None = LIMIT) -> str:
-    """Lay out the tiny spec under ``root`` as a checkout would hold it."""
-    for d in ("configs", "traffic", "limits"):
+    """Lay out the tiny spec under ``root`` as a checkout would hold it,
+    with the repo's architecture modules."""
+    for d in ("configs", "traffic", "limits", "models"):
         os.makedirs(os.path.join(root, "bench", d), exist_ok=True)
+    for f in glob.glob(os.path.join(BENCH, "models", "*.py")):
+        shutil.copy(f, os.path.join(root, "bench", "models"))
 
     def dump(path, obj):
         with open(os.path.join(root, path), "w") as f:
@@ -90,3 +105,22 @@ def write(root: str, *, limit: float | None = LIMIT) -> str:
         if limit is not None:
             dump(f"bench/limits/tiny.{name}.json", {"max_logit_gap": {"limit": limit}})
     return root
+
+
+@pytest.fixture
+def tiny(tmp_path, monkeypatch):
+    """The tiny spec as the checkout, with JAX's settings put back after."""
+    import harness
+    import jax
+
+    write(str(tmp_path))
+    monkeypatch.setattr(harness, "ROOT", str(tmp_path))
+    monkeypatch.setattr(harness, "CACHE", str(tmp_path / "cache"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "unused"))
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir", "jax_persistent_cache_min_compile_time_secs",
+        "jax_enable_compilation_cache")}
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield tmp_path
+    for k, v in saved.items():
+        jax.config.update(k, v)

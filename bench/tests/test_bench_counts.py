@@ -1,13 +1,15 @@
-"""The benchmark's operation and byte counts against hand arithmetic, and
-its peaks table."""
+"""The dense architecture's operation and byte counts
+(``bench/models/dense_gqa.py``) against hand arithmetic, and the peaks
+table."""
 import pytest
 
-import bench_tiny  # noqa: F401  (puts bench/ on the path)
+import bench_tiny
 import counts
 
+ARCH = bench_tiny.arch()
 # L=2, D=8, H=4, KV=2, hd=2, F=16, V=10, biased MLP, layer norms, bf16
-SMALL = counts.Dims(layers=2, d_model=8, heads=4, kv_heads=2, head_dim=2,
-                    d_ff=16, vocab=10, mlp_bias=True)
+SMALL = ARCH.Dims(layers=2, d_model=8, heads=4, kv_heads=2, head_dim=2,
+                  d_ff=16, vocab=10, mlp_bias=True)
 
 
 def test_linear_flops_per_token_by_hand():
@@ -37,7 +39,7 @@ def test_decode_bytes_by_hand():
 
 
 def test_dims_from_a_configuration_file():
-    d = counts.Dims.from_model(bench_tiny.MODEL)
+    d = ARCH.dims(bench_tiny.MODEL)
     assert (d.layers, d.d_model, d.heads, d.kv_heads, d.head_dim, d.d_ff,
             d.vocab, d.mlp_bias, d.layernorm, d.dtype_bytes) == (
         2, 64, 4, 2, 16, 128, 512, True, True, 2)

@@ -10,7 +10,7 @@ import counts
 import engine_trace
 import run
 
-DIMS = counts.Dims.from_model(bench_tiny.MODEL)   # 2 layers, d 64, ff 128, vocab 512
+DIMS = bench_tiny.arch().dims(bench_tiny.MODEL)   # 2 layers, d 64, ff 128, vocab 512
 PEAKS = counts.PEAKS["TPU v5 lite"]
 
 

@@ -11,7 +11,8 @@ import bench_tiny  # noqa: F401  (puts bench/ on the path)
 import engine_trace as et
 import harness
 import trace_reduce as tr
-from test_bench_run import _run, tiny  # noqa: F401  (the tiny checkout fixture)
+from bench_tiny import tiny  # noqa: F401  (the tiny checkout fixture)
+from test_bench_run import _run
 from test_bench_trace_reduce import DEVICE, HOST
 
 CPU_TRACE = os.path.join(os.path.dirname(__file__), "data", "cpu.xplane.pb")

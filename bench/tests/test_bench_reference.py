@@ -3,6 +3,7 @@ CPU: ``model.prefill`` and then decoding through the slot engine's cache
 (``ref`` backend) give the reference's logits, and the fp8 control does
 not."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -10,7 +11,6 @@ import numpy as np
 import pytest
 
 import bench_tiny
-import harness
 import reference
 import weights
 from repro.models.build import build_model
@@ -19,6 +19,7 @@ from repro.serving import ServingEngine
 SEED = 2**31 + 7
 PROMPT = [5, 17, 301, 42, 9, 77, 140, 3, 256, 11, 64]
 NEW = 8
+ARCH = bench_tiny.arch()
 
 
 def _conf(norm_type: str, dtype: str) -> dict:
@@ -32,9 +33,9 @@ def _conf(norm_type: str, dtype: str) -> dict:
 
 def _served(conf: dict):
     """Program logits: prefill's last position, then each decode step."""
-    cfg = harness.arch_config(conf)
+    cfg = ARCH.program_config(conf)
     model = build_model(cfg)
-    params = weights.make(conf["model"], SEED)
+    params = weights.make(ARCH, conf["model"], SEED)
     logits, _ = model.prefill(params, {"tokens": jnp.asarray([PROMPT], jnp.int32)},
                               max_len=32)
     engine = ServingEngine(model, params, slots=2, max_len=32)
@@ -48,9 +49,12 @@ def _served(conf: dict):
     return params, req.generated, np.stack(steps[:len(req.generated)])
 
 
+def _hidden(params, conf: dict):
+    return functools.partial(ARCH.final_hidden, params, ARCH.ref_config(conf["model"]))
+
+
 def _reference_logits(params, m: dict, seq: list[int]) -> np.ndarray:
-    cfg = reference.RefCfg.from_model(m)
-    h = reference.final_hidden(params, cfg, np.asarray(seq, np.int32))
+    h = ARCH.final_hidden(params, ARCH.ref_config(m), np.asarray(seq, np.int32))
     w = params["lm_head"].astype(jnp.float32)
     return np.asarray(jnp.dot(h, w, precision=reference.HIGHEST))
 
@@ -70,8 +74,7 @@ def test_reference_matches_prefill_and_slot_decode_in_f32(norm_type):
 def test_gaps_of_served_tokens_and_the_fp8_control():
     conf = _conf("layer_norm", "bfloat16")
     params, served, _ = _served(conf)
-    cfg = reference.RefCfg.from_model(conf["model"])
-    g = reference.gaps(params, cfg, PROMPT, served, control=True)
+    g = reference.gaps(params, _hidden(params, conf), PROMPT, served, control=True)
     assert g["served"].shape == g["control"].shape == (NEW,)
     assert np.all(g["served"] >= 0) and np.all(g["control"] >= 0)
     assert g["served"].max() < 0.05        # bf16 rounding around the f32 best
@@ -82,40 +85,39 @@ def test_gaps_of_served_tokens_and_the_fp8_control():
 def test_gaps_read_the_served_token_not_the_best():
     conf = _conf("layer_norm", "float32")
     params, served, _ = _served(conf)
-    cfg = reference.RefCfg.from_model(conf["model"])
     wrong = list(served)
     wrong[3] = (wrong[3] + 1) % 512
     ref = _reference_logits(params, conf["model"], PROMPT + served[:-1])
     row = ref[len(PROMPT) - 1 + 3]
-    g = reference.gaps(params, cfg, PROMPT, wrong[:4])
+    g = reference.gaps(params, _hidden(params, conf), PROMPT, wrong[:4])
     assert g["served"][3] == pytest.approx(row.max() - row[wrong[3]], abs=1e-4)
     assert g["served"][:3].max() < 1e-4
 
 
-def test_reference_refuses_what_it_does_not_compute():
-    m = dict(bench_tiny.MODEL, mlp_activation="relu2")
-    with pytest.raises(ValueError, match="tanh-GELU"):
-        reference.RefCfg.from_model(m)
-    with pytest.raises(ValueError, match="whole heads"):
-        reference.RefCfg.from_model(dict(bench_tiny.MODEL, rotary_fraction=0.5))
-    with pytest.raises(ValueError, match="untied head"):
-        reference.RefCfg.from_model(dict(bench_tiny.MODEL, tie_word_embeddings=True))
+@pytest.mark.parametrize("change,match", [
+    ({"mlp_activation": "relu2"}, "tanh-GELU"),
+    ({"rotary_fraction": 0.5}, "whole heads"),
+    ({"tie_word_embeddings": True}, "untied head"),
+], ids=["activation", "rotary_fraction", "tied_head"])
+def test_reference_refuses_what_it_does_not_compute(change, match):
+    with pytest.raises(ValueError, match=match):
+        ARCH.ref_config(dict(bench_tiny.MODEL, **change))
 
 
 def test_weights_are_seeded_and_in_the_served_dtype():
     m = bench_tiny.MODEL
-    a, b = weights.make(m, SEED), weights.make(m, SEED)
-    c = weights.make(m, SEED + 2**32)          # differs only above 32 bits
+    a, b = weights.make(ARCH, m, SEED), weights.make(ARCH, m, SEED)
+    c = weights.make(ARCH, m, SEED + 2**32)    # differs only above 32 bits
     la, lb, lc = (jax.tree_util.tree_leaves(x) for x in (a, b, c))
     assert all(x.dtype == jnp.bfloat16 for x in la)
     assert all(np.array_equal(np.asarray(x), np.asarray(y)) for x, y in zip(la, lb))
     assert not np.array_equal(np.asarray(a["embed"]), np.asarray(c["embed"]))
     shapes = jax.tree_util.tree_map(lambda x: x.shape, a)
-    program = jax.eval_shape(build_model(harness.arch_config(bench_tiny.CONFIG)).init,
+    program = jax.eval_shape(build_model(ARCH.program_config(bench_tiny.CONFIG)).init,
                              jax.random.PRNGKey(0))
     assert shapes == jax.tree_util.tree_map(lambda x: x.shape, program)
 
 
 def test_ref_cfg_is_hashable_for_jit():
-    cfg = reference.RefCfg.from_model(bench_tiny.MODEL)
+    cfg = ARCH.ref_config(bench_tiny.MODEL)
     assert hash(cfg) == hash(dataclasses.replace(cfg))

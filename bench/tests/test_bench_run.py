@@ -8,33 +8,17 @@ import re
 import subprocess
 import sys
 
-import jax
 import pytest
 
 import bench_tiny
 import calibrate
 import harness
 import run
+from bench_tiny import tiny  # noqa: F401  (a fixture)
 from repro.serving import ServingEngine
 
 REPO = bench_tiny.REPO
 SEED = 2**31 + 101
-
-
-@pytest.fixture
-def tiny(tmp_path, monkeypatch):
-    """The tiny spec as the checkout, with JAX's settings put back after."""
-    bench_tiny.write(str(tmp_path))
-    monkeypatch.setattr(harness, "ROOT", str(tmp_path))
-    monkeypatch.setattr(harness, "CACHE", str(tmp_path / "cache"))
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "unused"))
-    saved = {k: getattr(jax.config, k) for k in (
-        "jax_compilation_cache_dir", "jax_persistent_cache_min_compile_time_secs",
-        "jax_enable_compilation_cache")}
-    jax.config.update("jax_enable_compilation_cache", False)
-    yield tmp_path
-    for k, v in saved.items():
-        jax.config.update(k, v)
 
 
 def _run(cell: str, trace: int = 0, seconds: float = 3.0) -> dict:
@@ -186,7 +170,8 @@ def test_configs_are_files_under_paths_with_their_cuts(spec):
             assert NAME.match(key) and not WIDTH.search(key)
             assert key in conf["model"] and key in conf["published"]
             assert conf["model"][key] != conf["published"][key]
-        harness.arch_config(conf)        # the file is what the program runs
+        # the file is what the program runs
+        harness.arch_module(conf, c["file"]).program_config(conf)
 
 
 def test_cells_metrics_and_their_files(spec):
