@@ -9,11 +9,45 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
+import math
 from typing import Callable
 
 # ---------------------------------------------------------------------------
 # Architecture config
 # ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rope scaling (arXiv:2309.00071) as transformers' ``rope_type:
+    "yarn"`` computes it: inverse frequencies blended between interpolation
+    (divided by ``factor``) and extrapolation by a linear ramp over the
+    correction range that ``beta_fast`` and ``beta_slow`` rotations give at
+    ``original_max_positions``; cos and sin scaled by ``attention_factor``."""
+    factor: float
+    original_max_positions: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def inv_freq(self, head_dim: int, theta: float) -> tuple[float, ...]:
+        """The blended inverse frequencies of one head, in float64."""
+        def correction_dim(rotations: float) -> float:
+            return (head_dim * math.log(self.original_max_positions
+                                        / (rotations * 2 * math.pi))
+                    / (2 * math.log(theta)))
+
+        low = max(math.floor(correction_dim(self.beta_fast)), 0)
+        high = min(math.ceil(correction_dim(self.beta_slow)), head_dim - 1)
+        if low == high:
+            high += 0.001
+        out = []
+        for i in range(head_dim // 2):
+            extrapolation = theta ** (-2 * i / head_dim)
+            keep = 1.0 - min(max((i - low) / (high - low), 0.0), 1.0)
+            out.append(extrapolation / self.factor * (1.0 - keep)
+                       + extrapolation * keep)
+        return tuple(out)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,6 +74,7 @@ class ArchConfig:
     mlp_bias: bool = False       # biases on MLP projections (starcoder2, whisper)
     pos: str = "rope"            # rope | learned | none
     rope_theta: float = 10000.0
+    yarn: Yarn | None = None     # YaRN rope scaling on the G layers only
     # encoder-decoder (whisper): encoder layers + stub frontend length
     encoder_layers: int = 0
     encoder_seq: int = 0
@@ -175,6 +210,7 @@ ARCH_IDS = (
     "whisper-medium",
     "recurrentgemma-2b",
     "internvl2-26b",
+    "mellum2-12b-a2.5b",
 )
 
 _MODULE_FOR = {a: a.replace("-", "_").replace(".", "_") for a in ARCH_IDS}

@@ -42,8 +42,8 @@ def extract_kernels(cfg: ArchConfig, shape: ShapeConfig, *, dp: int = 1,
     tokens = b_local if decode else b_local * s
     uses: list[KernelUse] = []
 
-    def add(class_id: str, count: int, tag: str, **params):
-        uses.append(KernelUse(KernelInstance.make(class_id, dtype=dt, **params),
+    def add(class_id: str, count: int, tag: str, dtype: str = dt, **params):
+        uses.append(KernelUse(KernelInstance.make(class_id, dtype=dtype, **params),
                               use_count=count, tag=tag))
 
     kinds = cfg.layer_kinds
@@ -79,7 +79,10 @@ def extract_kernels(cfg: ArchConfig, shape: ShapeConfig, *, dp: int = 1,
             ep = cfg.n_experts % tp == 0 and tp > 1
             f_loc = f if ep else _div(f, tp)
             routed = max(tokens * cfg.moe_topk // (tp if ep else 1), 1)
-            add("moe_router", n_attn, "moe.router", M=tokens, N=cfg.n_experts, K=d)
+            # the router runs in float32 (models/mlp.py); the expert GEMMs
+            # over the routed rows, tokens · top-k, sorted by expert
+            add("moe_router", n_attn, "moe.router", dtype="float32", M=tokens,
+                N=cfg.n_experts, K=d)
             add("moe_gemm_silu_glu", n_attn, "moe.up", M=routed, N=2 * f_loc, K=d,
                 E=e_loc if ep else cfg.n_experts)
             add("moe_gemm", n_attn, "moe.down", M=routed, N=d, K=f_loc,

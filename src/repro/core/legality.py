@@ -68,8 +68,9 @@ def _block_extents(cs: ConcreteSchedule) -> dict[str, tuple[int, int]]:
     """axis -> (tile the kernel builds, extent of the array dim it tiles)."""
     p = cs.instance.p
     ext = {a: p[a] for a in cs.instance.axes}
-    if "E" in ext:  # grouped GEMM: each expert's block tiles M / E rows
-        ext["M"] = max(1, ext["M"] // ext["E"])
+    # Expert GEMMs: the ragged kernel tiles all M routed rows, the grouped
+    # (capacity) kernel each expert's M / E; a tile that passes against M
+    # passes against M / E, and fills no less VMEM.
     return {a: (min(cs.t[a], e), e) for a, e in ext.items()}
 
 
@@ -100,7 +101,8 @@ def vmem_bytes(cs: ConcreteSchedule, spec: ChipSpec) -> int:
         if inst.class_id == "matmul_residual":
             io += buf(bm, bn_out)
         scratch = buf(bm, bn, 4) if (cs.schedule.cache_write
-                                     or inst.class_id in GLU_CLASSES) else 0
+                                     or inst.class_id in GLU_CLASSES
+                                     or "E" in inst.p) else 0
         return 2 * io + scratch + buf(bm, bn, 4)          # + f32 dot result
     if inst.family == "attention":
         bq, bkv, d = b["Q"], b["KV"], inst.p.get("D", 128)

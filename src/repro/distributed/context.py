@@ -43,6 +43,14 @@ def set_sharding_rules(rules: dict | None) -> None:
     _tls.rules = rules or {}
 
 
+def sharded() -> bool:
+    """Whether a mesh's constraints are set: the residual-stream sharding or
+    any named rule.  Code with a layout the rules do not cover (the ragged
+    MoE dispatch) takes its sharded form then."""
+    return (getattr(_tls, "sharding", None) is not None
+            or bool(getattr(_tls, "rules", None)))
+
+
 def constrain_named(x: jax.Array, name: str) -> jax.Array:
     rules = getattr(_tls, "rules", None)
     if not rules or name not in rules:

@@ -253,6 +253,133 @@ def matmul(x: jax.Array, w: jax.Array, cs: ConcreteSchedule, *,
     return call(*args)
 
 
+# ---------------------------------------------------------------------------
+# Ragged grouped matmul: rows sorted by expert, one expert's weights a visit
+# ---------------------------------------------------------------------------
+
+
+def ragged_visits(group_sizes: jax.Array, m: int, bm: int):
+    """The (row tile, group) pairs a ragged matmul over ``m`` rows in tiles
+    of ``bm`` computes: every tile a non-empty group's rows touch, groups in
+    order, so a tile two groups share is visited twice in a row and an
+    empty group is never visited.  Returns ``(groups, tiles, visits,
+    starts, ends)``: the group and row tile of each grid step, padded to
+    the static bound ``m // bm + min(E, m)`` by repeating the last visit;
+    the number of real visits, (1,); each group's first and past-last row."""
+    e = group_sizes.shape[0]
+    sizes = group_sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first = starts // bm
+    count = jnp.where(sizes > 0, (ends - 1) // bm - first + 1, 0)
+    vend = jnp.cumsum(count)
+    visits = vend[-1]
+    steps = m // bm + min(e, m)
+    v = jnp.minimum(jnp.arange(steps, dtype=jnp.int32), jnp.maximum(visits - 1, 0))
+    groups = jnp.minimum(jnp.sum(vend[None, :] <= v[:, None], axis=1), e - 1)
+    tiles = first[groups] + v - (vend - count)[groups]
+    return (groups.astype(jnp.int32), tiles.astype(jnp.int32),
+            jnp.reshape(visits, (1,)), starts, ends)
+
+
+def _ragged_kernel(layer_ref, groups_ref, tiles_ref, visits_ref, starts_ref,
+                   ends_ref, x_ref, w_ref, o_ref, acc_ref, *, class_id: str,
+                   bm: int, k_trips: int, chunk: int, out_dtype):
+    """One K step of one visit (tile, group).  At the last K step the rows of
+    the tile that belong to the group take the result; the first visit of a
+    tile zeroes the rest, a later visit keeps what the earlier ones wrote."""
+    del layer_ref
+    v, kk = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(v < visits_ref[0])
+    def _():
+        @pl.when(kk == 0)
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
+                                preferred_element_type=jnp.float32)
+
+        @pl.when(kk == k_trips - 1)
+        def _():
+            group, tile = groups_ref[v], tiles_ref[v]
+            rows = tile * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
+            mine = (rows >= starts_ref[group]) & (rows < ends_ref[group])
+            y = _epilogue_fn(class_id, 0.0, chunk)(acc_ref[...])
+            first = (v == 0) | (tiles_ref[jnp.maximum(v - 1, 0)] != tile)
+            kept = jnp.where(first, 0.0, o_ref[...].astype(jnp.float32))
+            o_ref[...] = jnp.where(mine, y, kept).astype(out_dtype)
+
+
+def ragged_matmul(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
+                  cs: ConcreteSchedule, *, layer: jax.Array | int = 0,
+                  class_id: str = "moe_gemm", interpret: bool,
+                  vmem_limit_bytes: int = TPU_V5E.vmem_capacity) -> jax.Array:
+    """Ragged grouped (MoE expert) matmul: row ``i`` of ``x`` (M, K), sorted
+    by group, times the weight of its group; ``group_sizes`` (E,) counts
+    each group's rows, and rows past their sum belong to none (their output
+    rows are zero or never written).  ``w`` is (E, K, N), or a stack
+    (L, E, K, N) read in place at ``layer``.  Shape-changing (GLU)
+    epilogues emit N//2 columns.
+
+    The grid runs N tiles outermost, then the visits of
+    :func:`ragged_visits`, then K: a tile's visits are consecutive, so its
+    output block stays in VMEM between them.  The visit table, group rows
+    and layer are scalar-prefetched; the weight's index map picks the
+    visit's group.  Grid steps past the real visits compute nothing and
+    keep the last real visit's blocks, so they fetch nothing either.  The
+    schedule's M, N and K tiles are honoured; its order is not (the visits
+    must run inside the N loop) and the accumulator is always the f32
+    scratch."""
+    m, k = x.shape
+    stack = w[None] if w.ndim == 3 else w
+    n = stack.shape[3]
+    bm, bn, bk = min(cs.t["M"], m), min(cs.t["N"], n), min(cs.t["K"], k)
+    if k % bk:
+        raise ValueError(f"K tile {bk} does not divide K={k}")
+    shape_changing = class_id in SHAPE_CHANGING
+    chunk = glu_chunk(n // 2)
+    if shape_changing and bn != n and bn % (2 * chunk):
+        raise ValueError(f"GLU epilogue needs whole (gate, up) pairs of "
+                         f"{2 * chunk} columns per N tile, got {bn}")
+    bn_out = bn // 2 if shape_changing else bn
+    k_trips = k // bk
+    groups, tiles, visits, starts, ends = ragged_visits(group_sizes, m, bm)
+
+    def live(v, kk, visits_ref):
+        # a step past the real visits keeps the last real step's blocks
+        return jnp.where(v < visits_ref[0], kk, k_trips - 1)
+
+    def x_map(nn, v, kk, layer_ref, groups_ref, tiles_ref, visits_ref, *_):
+        return tiles_ref[v], live(v, kk, visits_ref)
+
+    def w_map(nn, v, kk, layer_ref, groups_ref, tiles_ref, visits_ref, *_):
+        return layer_ref[0], groups_ref[v], live(v, kk, visits_ref), nn
+
+    def o_map(nn, v, kk, layer_ref, groups_ref, tiles_ref, *_):
+        return tiles_ref[v], nn
+
+    kernel = functools.partial(_ragged_kernel, class_id=class_id, bm=bm,
+                               k_trips=k_trips, chunk=chunk, out_dtype=x.dtype)
+    call = pl.pallas_call(
+        kernel,
+        name=class_id,     # the op's name in the HLO and the device trace
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(pl.cdiv(n, bn), groups.shape[0], k_trips),
+            in_specs=[pl.BlockSpec((bm, bk), x_map),
+                      pl.BlockSpec((None, None, bk, bn), w_map)],
+            out_specs=pl.BlockSpec((bm, bn_out), o_map),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((m, n // 2 if shape_changing else n),
+                                       x.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes),
+        interpret=interpret,
+    )
+    return call(_layer_operand(layer), groups, tiles, visits, starts, ends, x, stack)
+
+
 def grouped_matmul(x: jax.Array, w: jax.Array, cs: ConcreteSchedule, *,
                    class_id: str = "moe_gemm", interpret: bool,
                    vmem_limit_bytes: int = TPU_V5E.vmem_capacity) -> jax.Array:

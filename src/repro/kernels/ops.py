@@ -106,8 +106,11 @@ class ScheduleProvider:
         self._plan_misses = 0
         # Pallas matmul weight reads, counted at trace time: "in_place" adds
         # a stack's depth for each traced call on a LayerRef (the call runs
-        # once per layer of the scan), "plain" adds 1 for a plain array.
+        # once per layer of the scan), "plain" adds 1 for a plain array
+        # (inside a layer scan, a slice of the stack copied out first).
+        # The ragged expert GEMMs' expert stacks are counted apart.
         self._weight_reads = {"in_place": 0, "plain": 0}
+        self._expert_reads = {"in_place": 0, "plain": 0}
 
     @property
     def mode(self) -> str:
@@ -137,6 +140,10 @@ class ScheduleProvider:
         with self._lock:
             self._weight_reads["in_place" if in_place else "plain"] += layers
 
+    def count_expert_read(self, in_place: bool, layers: int) -> None:
+        with self._lock:
+            self._expert_reads["in_place" if in_place else "plain"] += layers
+
     @property
     def vmem_limit_bytes(self) -> int:
         """The target chip's VMEM budget: the legality rule sizes blocks
@@ -156,6 +163,7 @@ class ScheduleProvider:
             out["plan_served"] = dict(self._plan_served)
             out["plan_misses"] = self._plan_misses
             out["matmul_weights"] = dict(self._weight_reads)
+            out["expert_weights"] = dict(self._expert_reads)
         out["plan_hits"] = sum(out["plan_served"].values())
         out["plan_entries"] = len(self.plan) if self.plan is not None else 0
         out["plan_generation"] = (self.plan.generation
@@ -278,11 +286,39 @@ def matmul(x: jax.Array, w: jax.Array | LayerRef, *, class_id: str = "matmul",
     return y.reshape(*lead, y.shape[-1])
 
 
-def moe_gemm(x: jax.Array, w: jax.Array, *, class_id: str = "moe_gemm",
+def moe_gemm(x: jax.Array, w: jax.Array | LayerRef, *,
+             group_sizes: jax.Array | None = None, class_id: str = "moe_gemm",
              provider: ScheduleProvider | None = None,
              backend: str | None = None) -> jax.Array:
-    """Grouped expert GEMM: x (E, M, K) @ w (E, K, N)."""
+    """Expert GEMM, in one of two layouts.
+
+    * ``group_sizes`` given (ragged, the dropless dispatch): x (M, K) holds the
+      routed rows sorted by expert, ``group_sizes`` (E,) counts each
+      expert's rows, and row i is multiplied by its expert's (K, N) weight.
+      Rows past the counted ones are no expert's: the ref backend gives
+      zeros there, the Pallas kernel leaves a tile that no group reaches
+      unwritten, so the caller masks them.  ``w`` is (E, K, N) or a
+      :class:`LayerRef` to a stack (L, E, K, N), read in place.  The schedule is keyed on the routed rows: (M, N, K, E).
+    * otherwise (the capacity dispatch, on a mesh): x (E, M, K) @ w
+      (E, K, N), every expert's M rows, keyed on M·E."""
     backend = backend or _default_backend()
+    in_place = isinstance(w, LayerRef)
+    if group_sizes is not None:
+        stack, layer = (w.stack, w.index) if in_place else (w, 0)
+        if backend == "ref":
+            ws = (jax.lax.dynamic_index_in_dim(stack, layer, keepdims=False)
+                  if in_place else stack)
+            y = jax.lax.ragged_dot(x, ws, group_sizes.astype(jnp.int32),
+                                   preferred_element_type=jnp.float32)
+            return ref.apply_epilogue(y, class_id).astype(x.dtype)
+        m, k = x.shape
+        e, n = stack.shape[-3], stack.shape[-1]
+        inst = _instance(class_id, x.dtype, M=m, N=n, K=k, E=e)
+        provider = _resolve(provider)
+        provider.count_expert_read(in_place, stack.shape[0] if in_place else 1)
+        return _mm.ragged_matmul(x, stack, group_sizes, provider.get(inst),
+                                 layer=layer, class_id=class_id,
+                                 **_kernel_kw(provider))
     if backend == "ref":
         return jax.vmap(lambda a, b: ref.matmul(a, b, class_id))(x, w)
     e, m, k = x.shape

@@ -51,11 +51,13 @@ def _qkv(p: dict, cfg: ArchConfig, x: jax.Array, provider) -> tuple[jax.Array, j
     return q, k, v
 
 
-def _rope_qk(cfg: ArchConfig, q, k, positions):
+def _rope_qk(cfg: ArchConfig, q, k, positions, kind: str):
+    """Rotary embedding of q and k; ``cfg.yarn`` scales it on G layers."""
     if cfg.pos != "rope":
         return q, k
-    return (apply_rope(q, positions, cfg.rope_theta),
-            apply_rope(k, positions, cfg.rope_theta))
+    yarn = cfg.yarn if kind == "G" else None
+    return (apply_rope(q, positions, cfg.rope_theta, yarn),
+            apply_rope(k, positions, cfg.rope_theta, yarn))
 
 
 # ---------------------------------------------------------------------------
@@ -84,14 +86,19 @@ def _cache_size(cache: dict) -> int:
 
 def attn_forward(p: dict, cfg: ArchConfig, x: jax.Array, kind: str, *,
                  positions: jax.Array, provider=None,
-                 cache: dict | None = None) -> tuple[jax.Array, dict | None]:
-    """x: (B, S, D) normalized input. Returns (attn_out, updated_cache)."""
+                 cache: dict | None = None,
+                 true_len=None) -> tuple[jax.Array, dict | None]:
+    """x: (B, S, D) normalized input. Returns (attn_out, updated_cache).
+
+    ``true_len`` (traced or static; None means S) counts the real positions
+    of a right-padded prompt: a ring cache keeps the last ``size`` of those,
+    never pad rows."""
     b, s, _ = x.shape
     q, k, v = _qkv(p, cfg, x, provider)
     q = jnp.swapaxes(q, 1, 2)  # (B, H, S, hd)
     k = jnp.swapaxes(k, 1, 2)
     v = jnp.swapaxes(v, 1, 2)
-    q, k = _rope_qk(cfg, q, k, positions)
+    q, k = _rope_qk(cfg, q, k, positions, kind)
 
     window = cfg.window if kind == "L" else 0
     out = ops.flash_attention(
@@ -113,12 +120,13 @@ def attn_forward(p: dict, cfg: ArchConfig, x: jax.Array, kind: str, *,
                 "k": jax.lax.dynamic_update_slice(cache["k"], k, (0, 0, 0, 0)),
                 "v": jax.lax.dynamic_update_slice(cache["v"], v, (0, 0, 0, 0)),
             }
-        else:  # ring prefill: keep last `size` positions, slot convention p % size
-            shift = (s - size) % size
+        else:  # ring prefill: keep the last `size` true positions, slot p % size
+            n = jnp.asarray(s if true_len is None else true_len, jnp.int32)
+            start = jnp.maximum(n - size, 0)
             new_cache = {
-                "k": jnp.roll(k[:, :, s - size:, :], shift, axis=2),
-                "v": jnp.roll(v[:, :, s - size:, :], shift, axis=2),
-            }
+                name: jnp.roll(jax.lax.dynamic_slice_in_dim(a, start, size, axis=2),
+                               start % size, axis=2)
+                for name, a in (("k", k), ("v", v))}
     return y, new_cache
 
 
@@ -147,7 +155,7 @@ def attn_chunk(p: dict, cfg: ArchConfig, x: jax.Array, kind: str, *,
     q = jnp.swapaxes(q, 1, 2)  # (B, H, C, hd)
     k = jnp.swapaxes(k, 1, 2)  # (B, KV, C, hd)
     v = jnp.swapaxes(v, 1, 2)
-    q, k = _rope_qk(cfg, q, k, positions)
+    q, k = _rope_qk(cfg, q, k, positions, kind)
 
     size = _cache_size(cache)
     window = cfg.window if kind == "L" else 0
@@ -236,7 +244,7 @@ def attn_decode(p: dict, cfg: ArchConfig, x: jax.Array, kind: str, *,
     q = jnp.swapaxes(q, 1, 2)   # (B, H, 1, hd)
     k = jnp.swapaxes(k, 1, 2)   # (B, KV, 1, hd)
     v = jnp.swapaxes(v, 1, 2)
-    q, k = _rope_qk(cfg, q, k, pos[:, None])
+    q, k = _rope_qk(cfg, q, k, pos[:, None], kind)
 
     size = _cache_size(cache)
     slot = jnp.where(size > pos, pos, pos % size)           # (B,) ring for local
@@ -291,7 +299,7 @@ def attn_verify(p: dict, cfg: ArchConfig, x: jax.Array, kind: str, *,
     k = jnp.swapaxes(k, 1, 2)   # (B, KV, C, hd)
     v = jnp.swapaxes(v, 1, 2)
     positions = off[:, None] + jnp.arange(s)                # (B, C)
-    q, k = _rope_qk(cfg, q, k, positions)
+    q, k = _rope_qk(cfg, q, k, positions, kind)
 
     size = _cache_size(cache)
     bi = jnp.arange(b)[:, None, None]

@@ -88,15 +88,23 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return theta ** (-jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (B, H, S, D); positions: (B, S) or (S,) absolute positions."""
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               yarn=None) -> jax.Array:
+    """x: (B, H, S, D); positions: (B, S) or (S,) absolute positions.
+    ``yarn`` (a :class:`~repro.configs.base.Yarn`) replaces the frequencies
+    by its blended ones and scales cos and sin by its attention factor."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                      # (D/2,)
+    if yarn is None:
+        freqs = rope_freqs(d, theta)                  # (D/2,)
+    else:
+        freqs = jnp.asarray(yarn.inv_freq(d, theta), jnp.float32)
     if positions.ndim == 1:
         positions = positions[None, :]
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B, S, D/2)
     cos = jnp.cos(ang)[:, None, :, :]
     sin = jnp.sin(ang)[:, None, :, :]
+    if yarn is not None:
+        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., 0::2], xf[..., 1::2]
     y1 = x1 * cos - x2 * sin

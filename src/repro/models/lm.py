@@ -64,14 +64,15 @@ def block_params(key: jax.Array, cfg: ArchConfig, kind: str) -> dict:
 def apply_block(p: dict, cfg: ArchConfig, kind: str, x: jax.Array, *,
                 positions: jax.Array | None, pos: jax.Array | None,
                 cache: dict | None, decode: bool, off: jax.Array | None = None,
-                verify: bool = False,
+                verify: bool = False, true_len=None,
                 provider=None) -> tuple[jax.Array, dict | None, jax.Array]:
     """Returns (x, new_cache, aux_loss).  ``off`` selects the chunked-prefill
     attention path: the slice starts at absolute position ``off`` against a
     partially filled cache (recurrent blocks already carry state through
     their cache, so R layers need no separate chunk path).  ``verify``
     reinterprets ``off`` as per-lane (B,) offsets for the speculative
-    verify path (attention layers only)."""
+    verify path (attention layers only).  ``true_len`` (prefill) counts the
+    real positions of a right-padded prompt: pad tokens route to no expert."""
     aux = jnp.zeros((), jnp.float32)
     if kind == "R":
         if verify:
@@ -98,14 +99,16 @@ def apply_block(p: dict, cfg: ArchConfig, kind: str, x: jax.Array, *,
                                off=off, cache=cache, provider=provider)
     else:
         a, c = attn.attn_forward(p["attn"], cfg, xn, kind, positions=positions,
-                                 cache=cache, provider=provider)
+                                 cache=cache, true_len=true_len,
+                                 provider=provider)
     # constrain the residual after every sub-block: otherwise GSPMD
     # replicates intermediate residuals inside multi-layer pattern groups
     # and pays full all-reduces instead of staying D-sharded (§Perf it-6)
     x = constrain(x + a)
     xn2 = apply_norm(p["ln2"], x, cfg.norm)
     if cfg.n_experts > 0:
-        y, aux = mlpm.moe_apply(p["moe"], cfg, xn2, provider=provider)
+        valid = None if true_len is None else positions < true_len
+        y, aux = mlpm.moe_apply(p["moe"], cfg, xn2, provider=provider, valid=valid)
         x = constrain(x + y)
     else:
         x = constrain(x + mlpm.mlp_apply(p["mlp"], cfg, xn2, provider=provider))
@@ -134,17 +137,20 @@ def _pattern_split(cfg: ArchConfig) -> tuple[tuple[str, ...], int, tuple[str, ..
 def _layer_params(groups: dict, l: jax.Array) -> dict:
     """Layer ``l`` of the stacked group params, for the serving scans.  The
     dense projections of attention and MLP blocks (the 3-D leaves of every
-    ``attn`` / ``mlp`` group) become :class:`~repro.kernels.ops.LayerRef`,
-    which the Pallas matmul reads in place from the stack; the rest (norm
-    scales, biases, recurrent and MoE leaves) is sliced: small, or read by
-    code other than the matmul."""
+    ``attn`` / ``mlp`` group) and the expert stacks of MoE blocks (the 4-D
+    leaves of ``moe``) become :class:`~repro.kernels.ops.LayerRef`, which
+    the Pallas matmuls read in place from the stack; the rest (norm scales,
+    biases, the router, recurrent leaves) is sliced: small, or read by code
+    other than the matmul."""
     def take(leaf):
         return jax.lax.dynamic_index_in_dim(leaf, l, keepdims=False)
 
+    in_place = {"attn": 3, "mlp": 3, "moe": 4}    # block -> stacked ndim
+
     def block(stacked: dict) -> dict:
-        return {name: ({k: ops.LayerRef(w, l) if w.ndim == 3 else take(w)
-                        for k, w in sub.items()}
-                       if name in ("attn", "mlp")
+        return {name: ({k: ops.LayerRef(w, l) if w.ndim == in_place[name]
+                        else take(w) for k, w in sub.items()}
+                       if name in in_place
                        else jax.tree_util.tree_map(take, sub))
                 for name, sub in stacked.items()}
 
@@ -198,10 +204,12 @@ def _embed(params: dict, cfg: ArchConfig, tokens: jax.Array) -> jax.Array:
 def _stack_pass(params: dict, cfg: ArchConfig, h: jax.Array, *,
                 positions: jax.Array, caches: dict | None, remat: bool,
                 off: jax.Array | None = None, verify: bool = False,
+                true_len=None,
                 provider=None) -> tuple[jax.Array, dict | None, jax.Array]:
     """Run all layers. caches: {"groups": {i: stacked}, "tail": [...]} or None.
     ``off`` (with caches) runs the chunked-prefill path for attention layers;
-    ``verify`` the speculative verify path (``off`` per-lane)."""
+    ``verify`` the speculative verify path (``off`` per-lane); ``true_len``
+    the real length of a right-padded prefill."""
     pat, reps, tail = _pattern_split(cfg)
 
     def group_body(carry, xs):
@@ -213,7 +221,7 @@ def _stack_pass(params: dict, cfg: ArchConfig, h: jax.Array, *,
             hh, c_out, a = apply_block(layer_params[str(i)], cfg, kind, hh,
                                        positions=positions, pos=None, cache=c_in,
                                        decode=False, off=off, verify=verify,
-                                       provider=provider)
+                                       true_len=true_len, provider=provider)
             aux = aux + a
             if c_out is not None:
                 new_cache[str(i)] = c_out
@@ -244,7 +252,7 @@ def _stack_pass(params: dict, cfg: ArchConfig, h: jax.Array, *,
         h, c_out, a = apply_block(params["tail"][j], cfg, kind, h,
                                   positions=positions, pos=None, cache=c_in,
                                   decode=False, off=off, verify=verify,
-                                  provider=provider)
+                                  true_len=true_len, provider=provider)
         aux = aux + a
         if caches is not None:
             new_caches["tail"].append(c_out)
@@ -321,7 +329,8 @@ def prefill(params: dict, cfg: ArchConfig, batch: dict, *, max_len: int,
     from the last real position and the cache's decode position starts
     there.  Right padding is inert for causal attention (real positions
     never attend to pads; pad cache rows sit beyond the decode position and
-    are overwritten before they become visible)."""
+    are overwritten before they become visible), a ring cache keeps the
+    last true positions, never pads, and pad tokens route to no expert."""
     tokens = batch["tokens"]
     h = _embed(params, cfg, tokens)
     if cfg.vision_tokens:
@@ -331,13 +340,15 @@ def prefill(params: dict, cfg: ArchConfig, batch: dict, *, max_len: int,
     b, s, _ = h.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     caches = init_cache(cfg, b, max_len)
+    t = None if true_len is None else (jnp.asarray(true_len, jnp.int32)
+                                       + cfg.vision_tokens)
     h, new_caches, _ = _stack_pass(params, cfg, h, positions=positions,
-                                   caches=caches, remat=False, provider=provider)
-    if true_len is None:
+                                   caches=caches, remat=False, true_len=t,
+                                   provider=provider)
+    if t is None:
         t = jnp.asarray(s, jnp.int32)
         h_last = h[:, -1:, :]
     else:
-        t = jnp.asarray(true_len, jnp.int32) + cfg.vision_tokens
         h_last = jax.lax.dynamic_slice_in_dim(h, t - 1, 1, axis=1)
     new_caches["t"] = jnp.full((b,), t, jnp.int32)
     h_last = apply_norm(params["final_norm"], h_last, cfg.norm)

@@ -21,11 +21,11 @@ Two serving-cost refinements live here:
 * **Prefill buckets** — prompts are padded (right, causal-safe) to
   power-of-two length buckets so the prefill trace count is O(log max_len)
   instead of one per distinct prompt length.  The model is told the true
-  length (``true_len``) so logits and cache positions are exact.  Bucketing
-  is enabled only where padding is provably inert: attention-only stacks
-  (a recurrent scan would fold pad steps into its state) and pad lengths
-  that fit the smallest KV cache (a ring/SWA cache would wrap pad rows over
-  real ones); everything else falls back to exact-length prefill.
+  length (``true_len``) so logits, cache positions, the rows a ring (SWA)
+  cache keeps and the tokens routed to experts are exact.  Bucketing is
+  enabled only where padding is provably inert: attention-only stacks (a
+  recurrent scan would fold pad steps into its state); everything else
+  falls back to exact-length prefill.
 
 This is the TPU-idiomatic shape of continuous batching for fixed-size
 caches; ring buffers (windowed layers) and recurrent states come from the
@@ -118,13 +118,8 @@ class ServingEngine:
         self._admitted_at: dict[int, float] = {}   # uid -> span start, while tracing
 
         cfg = model.cfg
-        kinds = set(cfg.layer_kinds)
         self.prefill_buckets = (prefill_buckets and cfg.family != "audio"
-                                and "R" not in kinds)
-        # Largest pad length that cannot corrupt a cache: the ring (windowed)
-        # caches hold min(window, max_len) positions and wrap beyond that.
-        self._bucket_cap = (max_len if (cfg.window == 0 or "L" not in kinds)
-                            else min(cfg.window, max_len))
+                                and "R" not in cfg.layer_kinds)
         self._prefill_lengths: set[int] = set()  # distinct padded lengths traced
         # padding-waste ledger: true prompt tokens vs padded tokens computed
         # (the paged engine's chunked prefill holds these equal)
@@ -177,20 +172,20 @@ class ServingEngine:
 
     # -- prefill buckets -------------------------------------------------------
     def _pad_len(self, n: int) -> int:
-        """Power-of-two bucket for a prompt of n tokens (n itself when
-        bucketing is off or the bucket would overflow the smallest cache)."""
-        if not self.prefill_buckets or n >= self._bucket_cap:
+        """Power-of-two bucket for a prompt of n tokens, at most max_len (n
+        itself when bucketing is off)."""
+        if not self.prefill_buckets or n >= self.max_len:
             return n
         b = 1
         while b < n:
             b *= 2
-        return min(b, self._bucket_cap)
+        return min(b, self.max_len)
 
     def _bucket_lengths(self) -> list[int]:
         """Every pad length prefill can be traced at (for plan coverage)."""
         if not self.prefill_buckets:
             return []
-        return prefill_bucket_lengths(self._bucket_cap)
+        return prefill_bucket_lengths(self.max_len)
 
     @property
     def prefill_trace_count(self) -> int:

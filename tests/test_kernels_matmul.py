@@ -165,3 +165,46 @@ def test_layer_indexed_matmul_reads_the_stack_in_place(class_id, n, tn, m):
         want = mk.matmul(x, stack[layer], cs, class_id=class_id, bias=bias,
                          interpret=True)
         np.testing.assert_array_equal(got, want)
+
+
+# ---------------------------------------------------------------------------
+# Ragged grouped matmul: rows sorted by expert
+# ---------------------------------------------------------------------------
+
+
+def test_ragged_visits_skip_empty_groups_and_revisit_shared_tiles():
+    """Groups of 5, 0, 17 and 2 rows in tiles of 8: expert 0 takes tile 0,
+    the empty expert 1 no visit, expert 2 tiles 0-2 and expert 3 tile 2;
+    the table pads to the bound 24 // 8 + 4 with the last visit."""
+    groups, tiles, visits, starts, ends = mk.ragged_visits(
+        jnp.asarray([5, 0, 17, 2], jnp.int32), 24, 8)
+    assert int(visits[0]) == 5
+    assert groups.shape == tiles.shape == (7,)
+    assert groups.tolist() == [0, 2, 2, 2, 3, 3, 3]
+    assert tiles.tolist() == [0, 0, 1, 2, 2, 2, 2]
+    assert starts.tolist() == [0, 5, 5, 22] and ends.tolist() == [5, 5, 22, 24]
+
+
+@pytest.mark.parametrize("sizes", [(5, 0, 17, 2), (0, 24, 0, 0), (7, 7, 7, 3)],
+                         ids=["uneven_with_an_empty_expert", "one_expert_takes_every_row",
+                              "sizes_that_do_not_divide_the_tile"])
+@pytest.mark.parametrize("class_id,n,tn", [("moe_gemm", 48, 16),
+                                           ("moe_gemm_silu_glu", 512, 256)])
+def test_ragged_matmul_matches_a_per_expert_loop(sizes, class_id, n, tn):
+    """Each expert's rows times its own weight, read in place from layer 1
+    of a (2, E, K, N) stack, in row tiles of 8 over two K blocks; three rows
+    past the groups belong to no expert."""
+    e, k = len(sizes), 32
+    m = sum(sizes) + 3
+    r = np.random.default_rng(sum(sizes) + n)
+    x = jnp.asarray(r.normal(size=(m, k)), jnp.float32)
+    stack = jnp.asarray(r.normal(size=(2, e, k, n)), jnp.float32)
+    inst = KernelInstance.make(class_id, M=m, N=n, K=k, E=e, dtype="float32")
+    cs = concretize(Schedule.make(class_id, {"M": 8, "N": tn, "K": 16, "E": 1}), inst)
+    got = jax.jit(lambda x, w, g: mk.ragged_matmul(
+        x, w, g, cs, layer=1, class_id=class_id, interpret=True))(
+            x, stack, jnp.asarray(sizes, jnp.int32))
+    bounds = np.cumsum((0,) + sizes)
+    want = jnp.concatenate([ref.matmul(x[lo:hi], stack[1, i], class_id)
+                            for i, (lo, hi) in enumerate(zip(bounds, bounds[1:]))])
+    np.testing.assert_allclose(got[:bounds[-1]], want, rtol=2e-4, atol=2e-4)

@@ -137,12 +137,14 @@ def _eqns(jaxpr):
 
 
 @pytest.mark.parametrize("arch", ["minitron-4b", "starcoder2-7b",
-                                  "recurrentgemma-2b", "mixtral-8x22b"])
+                                  "recurrentgemma-2b", "mixtral-8x22b",
+                                  "mellum2-12b-a2.5b"])
 def test_pallas_serving_matches_ref_over_decode_steps(arch, rng):
     """Prefill and three decode steps through the Pallas kernels (interpret
-    mode), which read every layer's attention and MLP projections in place
-    from the stacks, match the ref backend, which slices them: dense with and
-    without MLP biases, recurrent blocks' MLPs, and MoE blocks' attention."""
+    mode), which read every layer's attention and MLP projections and expert
+    stacks in place from the stacks, match the ref backend, which slices
+    them: dense with and without MLP biases, recurrent blocks' MLPs, MoE
+    blocks' attention and ragged expert GEMMs, ring caches and YaRN."""
     cfg, model, params = _lm_with_biases(arch)
     b, s, steps = 2, 10, 3
     toks = jnp.asarray(rng.integers(1, cfg.vocab_size, (b, s + steps)), jnp.int32)
@@ -188,3 +190,113 @@ def test_decode_step_copies_no_stacked_weight(arch):
     assert not copied
     assert provider.stats()["matmul_weights"] == {"in_place": 6 * cfg.n_layers,
                                                   "plain": 1}
+
+
+# ---------------------------------------------------------------------------
+# Experts on the serving paths: dropless, pads routed nowhere, read in place
+# ---------------------------------------------------------------------------
+
+
+def _expert(p, e, x):
+    """One expert's SwiGLU over rows x, from the interleaved (gate, up)
+    packing of ``w_in``."""
+    from repro.kernels import ref
+
+    h = ref.matmul(x, p["w_in"][e], "matmul_silu_glu")
+    return ref.matmul(h, p["w_out"][e], "matmul")
+
+
+@pytest.mark.parametrize("backend", ["ref", "pallas"])
+def test_dropless_moe_keeps_every_pair_past_4096(backend, rng):
+    """A router forced onto expert 0 (every token's top-1, expert 1 its
+    second by the tie order) puts all 2100 tokens on two experts: 4200
+    pairs, past the 4096 where the capacity dispatch caps each expert at
+    1312 rows and drops the rest.  The dropless dispatch, which ``moe_apply``
+    takes off a mesh, equals each token's two experts computed directly;
+    the capacity dispatch does not."""
+    from repro.models import mlp as mlpm
+
+    cfg = reduced(get_arch("mellum2-12b-a2.5b"))
+    p = mlpm.moe_params(jax.random.PRNGKey(4), cfg)
+    p["router"] = jnp.zeros_like(p["router"]).at[:, 0].set(1.0)
+    t = 2100
+    assert t * cfg.moe_topk > 4096
+    x = jnp.asarray(np.abs(rng.normal(size=(1, t, cfg.d_model))) + 0.1, jnp.float32)
+    probs = jax.nn.softmax(x[0] @ p["router"], axis=-1)
+    g = probs[:, :2] / probs[:, :2].sum(-1, keepdims=True)
+    want = g[:, :1] * _expert(p, 0, x[0]) + g[:, 1:] * _expert(p, 1, x[0])
+    with ops.use_backend(backend):
+        got, _ = mlpm.moe_apply(p, cfg, x)
+        probs_t = jax.nn.softmax(x[0] @ p["router"], axis=-1)
+        top_g, top_e = jax.lax.top_k(probs_t, cfg.moe_topk)
+        top_g = top_g / top_g.sum(-1, keepdims=True)
+        capped = mlpm._capacity(p, x[0], top_e, top_g, cfg.n_experts,
+                                mlpm.CAPACITY_FACTOR, None)
+    # f32 throughout: the two paths differ by summation order alone
+    np.testing.assert_allclose(got[0], want, rtol=1e-4, atol=1e-4)
+    assert not np.allclose(capped, want, rtol=1e-2, atol=1e-2)
+
+
+def test_bucketed_ring_prefill_past_the_window_equals_exact_length(rng):
+    """A 13-token prompt past the reduced window of 8 pads to the engine's
+    16 bucket: the ring caches keep the last 8 true positions (not pads),
+    the full caches the 13 true rows, pad tokens take no expert rows, and
+    the logits of the prefill and of a decode step after it equal an
+    exact-length prefill's."""
+    from repro.serving import ServingEngine
+
+    cfg = reduced(get_arch("mellum2-12b-a2.5b"))
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(5))
+    n, max_len = 13, 32
+    assert ServingEngine(model, params, slots=1, max_len=max_len).bucket_for(n) == 16
+    toks = jnp.asarray(rng.integers(1, cfg.vocab_size, (1, n + 1)), jnp.int32)
+    padded = jnp.pad(toks[:, :n], ((0, 0), (0, 16 - n)), constant_values=7)
+    exact_logits, exact = model.prefill(params, {"tokens": toks[:, :n]}, max_len=max_len)
+    bucket_logits, bucket = model.prefill(params, {"tokens": padded}, max_len=max_len,
+                                          true_len=jnp.int32(n))
+    # shapes differ (13 against 16 rows): reductions may round differently
+    np.testing.assert_allclose(bucket_logits, exact_logits, rtol=1e-5, atol=1e-5)
+    for path, a in jax.tree_util.tree_flatten_with_path(exact)[0]:
+        b = dict(jax.tree_util.tree_flatten_with_path(bucket)[0])[path]
+        rows = a.shape[-2] if a.ndim > 1 and a.shape[-2] == cfg.window else n
+        np.testing.assert_allclose(b[..., :rows, :] if a.ndim > 1 else b,
+                                   a[..., :rows, :] if a.ndim > 1 else a,
+                                   rtol=1e-5, atol=1e-5, err_msg=str(path))
+    step_exact, _ = model.decode_step(params, exact, toks[:, n])
+    step_bucket, _ = model.decode_step(params, bucket, toks[:, n])
+    np.testing.assert_allclose(step_bucket, step_exact, rtol=1e-5, atol=1e-5)
+
+
+def test_serving_scans_read_expert_stacks_in_place():
+    """Traced prefill and decode read each layer's expert stacks in place:
+    two ragged GEMMs per layer on a (L, E, K, N) stack, none on a slice,
+    and no 4-D stack is sliced or scanned over."""
+    cfg = reduced(get_arch("mellum2-12b-a2.5b"))
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(6))
+    stacks = {w.shape for w in jax.tree_util.tree_leaves(params["groups"])
+              if w.ndim == 4}
+    assert stacks
+    calls = {
+        "prefill": lambda p, c, t, provider: model.prefill(
+            p, {"tokens": t[:, None]}, max_len=8, provider=provider),
+        "decode": lambda p, c, t, provider: model.decode_step(
+            p, c, t, provider=provider),
+    }
+    for name, call in calls.items():
+        provider = ops.ScheduleProvider()
+        with ops.use_backend("pallas"):
+            closed = jax.make_jaxpr(lambda p, c, t: call(p, c, t, provider))(
+                params, model.init_cache(2, 8), jnp.zeros((2,), jnp.int32))
+        assert provider.stats()["expert_weights"] == {
+            "in_place": 2 * cfg.n_layers, "plain": 0}, name
+        for eqn in _eqns(closed.jaxpr):
+            if eqn.primitive.name in ("dynamic_slice", "slice", "gather"):
+                operands = eqn.invars[:1]
+            elif eqn.primitive.name == "scan":   # xs follow the consts and carry
+                operands = eqn.invars[eqn.params["num_consts"] + eqn.params["num_carry"]:]
+            else:
+                continue
+            assert not [v for v in operands
+                        if getattr(v.aval, "shape", ()) in stacks], (name, eqn)

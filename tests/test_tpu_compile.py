@@ -282,3 +282,28 @@ def test_layer_scan_reads_stacked_weights_in_place(one_chip, in_place):
     copies = re.findall(rf"%[\w.-]*dynamic-slice[\w.-]* = bf16\[(?:1,)?{D},{D}\]",
                         text)
     assert bool(copies) != in_place
+
+
+@pytest.mark.parametrize("class_id,n,k,tiles", [
+    ("moe_gemm_silu_glu", 2 * 896, 2304, {"M": 128, "N": 256, "K": 256, "E": 1}),
+    ("moe_gemm", 2304, 896, {"M": 128, "N": 384, "K": 128, "E": 1}),
+])
+def test_ragged_expert_gemm_compiles_reading_the_stack_in_place(one_chip, class_id,
+                                                               n, k, tiles):
+    """mellum2-12b-a2.5b's expert GEMMs over a 2048-token prefill's 16,384
+    routed rows, each expert's weights read in place from a (3, 64, K, N)
+    stack at a traced layer, with no copy of the stack."""
+    import re
+
+    m = 2048 * 8
+    inst = KernelInstance.make(class_id, M=m, N=n, K=k, E=64)
+    cs = concretize(Schedule.make(class_id, tiles, order=("E", "M", "N", "K")), inst)
+    legality.check(cs, TPU_V5E)
+    text = _compile(
+        lambda x, w, g, layer: mk.ragged_matmul(x, w, g, cs, layer=layer,
+                                                class_id=class_id, interpret=False),
+        _sds(one_chip, (m, k)), _sds(one_chip, (3, 64, k, n)),
+        _sds(one_chip, (64,), jnp.int32), _sds(one_chip, (), jnp.int32)).as_text()
+    assert "tpu_custom_call" in text
+    assert not re.findall(rf"= bf16\[(?:3,|1,)?64,{k},{n}\]\S* (?:copy|dynamic-slice|fusion)",
+                          text)
