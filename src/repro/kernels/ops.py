@@ -38,6 +38,7 @@ from repro.core import resolution
 from repro.core.resolution import ExecutionPlan, ResolutionPipeline
 from repro.core.schedule import ConcreteSchedule, Schedule
 from repro.core.workload import KernelInstance
+from repro.kernels import decode_attention as _da
 from repro.kernels import flash_attention as _fa
 from repro.kernels import matmul as _mm
 from repro.kernels import ref
@@ -111,6 +112,10 @@ class ScheduleProvider:
         # The ragged expert GEMMs' expert stacks are counted apart.
         self._weight_reads = {"in_place": 0, "plain": 0}
         self._expert_reads = {"in_place": 0, "plain": 0}
+        # Pallas decode-attention cache reads, counted the same way:
+        # "in_place" adds a stack's depth for a layer-indexed cache, "sliced"
+        # adds 1 for a plain per-layer cache.
+        self._kv_reads = {"in_place": 0, "sliced": 0}
 
     @property
     def mode(self) -> str:
@@ -144,6 +149,10 @@ class ScheduleProvider:
         with self._lock:
             self._expert_reads["in_place" if in_place else "plain"] += layers
 
+    def count_kv_read(self, in_place: bool, layers: int) -> None:
+        with self._lock:
+            self._kv_reads["in_place" if in_place else "sliced"] += layers
+
     @property
     def vmem_limit_bytes(self) -> int:
         """The target chip's VMEM budget: the legality rule sizes blocks
@@ -164,6 +173,7 @@ class ScheduleProvider:
             out["plan_misses"] = self._plan_misses
             out["matmul_weights"] = dict(self._weight_reads)
             out["expert_weights"] = dict(self._expert_reads)
+            out["kv_cache"] = dict(self._kv_reads)
         out["plan_hits"] = sum(out["plan_served"].values())
         out["plan_entries"] = len(self.plan) if self.plan is not None else 0
         out["plan_generation"] = (self.plan.generation
@@ -247,10 +257,12 @@ def _kernel_kw(provider: ScheduleProvider) -> dict:
 
 
 class LayerRef(NamedTuple):
-    """Layer ``index`` of a stacked weight ``stack`` (L, K, N), as a layer
-    scan hands it to :func:`matmul`: the Pallas kernel reads the layer's
-    blocks in place from the stack, where a slice ``stack[index]`` would be
-    copied out first."""
+    """Layer ``index`` of a stacked array ``stack`` (a weight (L, K, N), an
+    expert stack (L, E, K, N), a KV cache (L, B, KV, T, hd)), as a layer
+    scan hands it to :func:`matmul`, :func:`moe_gemm` or
+    :func:`decode_attention`: the Pallas kernel reads the layer's blocks in
+    place from the stack, where a slice ``stack[index]`` would be copied out
+    first."""
     stack: jax.Array
     index: jax.Array
 
@@ -351,6 +363,36 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return _fa.flash_attention(q, k, v, provider.get(inst), class_id=class_id,
                                causal=causal, window=window, softcap=softcap,
                                q_offset=q_offset, **_kernel_kw(provider))
+
+
+def decode_attention(q: jax.Array, k: jax.Array | LayerRef,
+                     v: jax.Array | LayerRef, pos: jax.Array, *,
+                     softcap: float = 0.0,
+                     provider: ScheduleProvider | None = None,
+                     backend: str | None = None) -> jax.Array:
+    """One query token per slot against its cache: q (B, Hq, 1, hd); k and v
+    (B, KV, T, hd), or :class:`LayerRef` s (one index) to stacks
+    (L, B, KV, T, hd), which the Pallas kernel reads in place.  Slot b
+    attends cache rows ``< min(pos[b] + 1, T)``: the causal mask of a full
+    cache, the written slots of a ring cache no longer than its window.
+    Float32 scores and sums.  The kernel's block comes from the shape: no
+    schedule is resolved."""
+    backend = backend or _default_backend()
+    in_place = isinstance(k, LayerRef)
+    if backend == "ref":
+        if in_place:
+            k = jax.lax.dynamic_index_in_dim(k.stack, k.index, keepdims=False)
+            v = jax.lax.dynamic_index_in_dim(v.stack, v.index, keepdims=False)
+        return ref.decode_attention(q, k, v, pos, softcap)
+    ks, vs, layer = (k.stack, v.stack, k.index) if in_place else (k, v, 0)
+    b, hq, _, d = q.shape
+    hkv, t = ks.shape[-3], ks.shape[-2]
+    provider = _resolve(provider)
+    provider.count_kv_read(in_place, ks.shape[0] if in_place else 1)
+    o = _da.decode_attention(q.reshape(b, hkv, hq // hkv, d), ks, vs,
+                             jnp.minimum(pos + 1, t), layer=layer,
+                             softcap=softcap, **_kernel_kw(provider))
+    return o.reshape(b, hq, 1, d).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -173,6 +173,33 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return o.reshape(b, hq, sq, d).astype(q.dtype)
 
 
+def masked_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                            valid_mask: jax.Array, softcap: float = 0.0) -> jax.Array:
+    """Single-query attention over the whole cache with an explicit (B, size)
+    validity mask (handles causal prefix and ring-buffer semantics).
+    q (B, Hq, 1, hd); k, v (B, KV, size, hd); float32 scores and sums."""
+    b, hq, _, d = q.shape
+    hkv = k.shape[1]
+    group = hq // hkv
+    qg = q.reshape(b, hkv, group, d).astype(jnp.float32) * d ** -0.5
+    s = jnp.einsum("bhgd,bhkd->bhgk", qg, k.astype(jnp.float32))
+    if softcap > 0:
+        s = jnp.tanh(s / softcap) * softcap
+    s = jnp.where(valid_mask[:, None, None, :], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bhgk,bhkd->bhgd", p, v.astype(jnp.float32))
+    return o.reshape(b, hq, 1, d).astype(q.dtype)
+
+
+def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array,
+                     softcap: float = 0.0) -> jax.Array:
+    """The decode kernel's oracle: slot b attends cache rows
+    ``< min(pos[b] + 1, size)``."""
+    size = k.shape[2]
+    valid = jnp.arange(size)[None, :] < jnp.minimum(pos + 1, size)[:, None]
+    return masked_decode_attention(q, k, v, valid, softcap)
+
+
 # ---------------------------------------------------------------------------
 # RWKV6 time-mix scan (Finch wkv: data-dependent per-channel decay + bonus)
 # ---------------------------------------------------------------------------

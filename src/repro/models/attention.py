@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.distributed.context import sharded
 from repro.kernels import ops
 from repro.models.common import apply_norm, apply_rope, dense_init, dtype_of, norm_params
 
@@ -76,7 +77,9 @@ def init_attn_cache(cfg: ArchConfig, kind: str, batch: int, max_len: int) -> dic
 
 
 def _cache_size(cache: dict) -> int:
-    return cache["k"].shape[2]
+    """Rows of a layer's cache, plain or a :class:`~repro.kernels.ops.LayerRef`."""
+    k = cache["k"]
+    return (k.stack if isinstance(k, ops.LayerRef) else k).shape[-2]
 
 
 # ---------------------------------------------------------------------------
@@ -214,8 +217,9 @@ def attn_chunk(p: dict, cfg: ArchConfig, x: jax.Array, kind: str, *,
 def _masked_chunk_attention(q, k, v, valid_mask, cfg: ArchConfig,
                             softcap: float = 0.0):
     """Multi-query attention with an explicit (C, T) validity mask — the
-    chunk analogue of :func:`_masked_decode_attention` (ring semantics need
-    per-position masks the flash kernel's causal/window params can't say)."""
+    chunk analogue of :func:`repro.kernels.ref.masked_decode_attention`
+    (ring semantics need per-position masks the flash kernel's
+    causal/window params can't say)."""
     b, hq, c, d = q.shape
     hkv = k.shape[1]
     group = hq // hkv
@@ -237,7 +241,16 @@ def _masked_chunk_attention(q, k, v, valid_mask, cfg: ArchConfig,
 def attn_decode(p: dict, cfg: ArchConfig, x: jax.Array, kind: str, *,
                 pos: jax.Array, cache: dict, provider=None) -> tuple[jax.Array, dict]:
     """x: (B, 1, D); pos: (B,) per-sequence absolute positions (continuous
-    batching: every slot may be at a different decode position)."""
+    batching: every slot may be at a different decode position).
+
+    ``cache`` is this layer's ``{"k", "v"}``, each (B, KV, T, hd), or, in
+    the decode step's layer scan, each an :class:`~repro.kernels.ops.LayerRef`
+    to the stack (L, B, KV, T, hd) the scan carries: the B new rows are then
+    written into the stack at the layer, the attention reads the layer in
+    place, and the returned cache is the same form over the updated stack.
+    Under a donated cache the write is in place, so the caller must not read
+    the cache it passed in again.  Under a mesh the attention is the einsum
+    of the ``ref`` backend, as the sharding rules lay the cache out."""
     b = x.shape[0]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     q, k, v = _qkv(p, cfg, x, provider)
@@ -250,24 +263,27 @@ def attn_decode(p: dict, cfg: ArchConfig, x: jax.Array, kind: str, *,
     slot = jnp.where(size > pos, pos, pos % size)           # (B,) ring for local
     bi = jnp.arange(b)[:, None]
     hi = jnp.arange(cfg.n_kv_heads)[None, :]
-    ck = cache["k"].at[bi, hi, slot[:, None], :].set(k[:, :, 0, :].astype(cache["k"].dtype))
-    cv = cache["v"].at[bi, hi, slot[:, None], :].set(v[:, :, 0, :].astype(cache["v"].dtype))
+    new_cache = {}
+    for name, row in (("k", k), ("v", v)):
+        c = cache[name]
+        if isinstance(c, ops.LayerRef):
+            stack = c.stack.at[c.index, bi, hi, slot[:, None]].set(
+                row[:, :, 0, :].astype(c.stack.dtype))
+            new_cache[name] = ops.LayerRef(stack, c.index)
+        else:
+            new_cache[name] = c.at[bi, hi, slot[:, None], :].set(
+                row[:, :, 0, :].astype(c.dtype))
 
-    window = cfg.window if kind == "L" else 0
-    slots = jnp.arange(size)[None, :]                       # (1, size)
-    if window and size <= window:
-        # Ring cache: live slots hold the last `size` (≤ window) positions,
-        # so the window constraint holds by construction; only not-yet-
-        # written slots (before the ring wraps) need masking.
-        valid = slots < jnp.minimum(pos + 1, size)[:, None]
-        out = _masked_decode_attention(q, ck, cv, valid, cfg)
-    else:
-        valid = slots <= pos[:, None]
-        out = _masked_decode_attention(q, ck, cv, valid, cfg,
-                                       softcap=cfg.attn_softcap if kind == "G" else 0.0)
+    # A full cache attends slots <= pos; a ring cache (L layers: its size is
+    # at most the window, so the window holds by construction) its written
+    # slots, < min(pos + 1, size): one mask, slot < min(pos + 1, size).
+    out = ops.decode_attention(q, new_cache["k"], new_cache["v"], pos,
+                               softcap=cfg.attn_softcap if kind == "G" else 0.0,
+                               provider=provider,
+                               backend="ref" if sharded() else None)
     out = jnp.swapaxes(out, 1, 2).reshape(b, 1, cfg.n_heads * cfg.head_dim)
     y = ops.matmul(out, p["wo"], provider=provider)
-    return y, {"k": ck, "v": cv}
+    return y, new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -333,19 +349,3 @@ def _masked_verify_attention(q, k, v, valid_mask, cfg: ArchConfig,
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhgqt,bhtd->bhgqd", p, v.astype(jnp.float32))
     return o.reshape(b, hq, c, d).astype(q.dtype)
-
-
-def _masked_decode_attention(q, k, v, valid_mask, cfg: ArchConfig, softcap: float = 0.0):
-    """Single-query attention over the whole cache with an explicit (B, size)
-    validity mask (handles causal prefix and ring-buffer semantics)."""
-    b, hq, _, d = q.shape
-    hkv = k.shape[1]
-    group = hq // hkv
-    qg = q.reshape(b, hkv, group, d).astype(jnp.float32) * d ** -0.5
-    s = jnp.einsum("bhgd,bhkd->bhgk", qg, k.astype(jnp.float32))
-    if softcap > 0:
-        s = jnp.tanh(s / softcap) * softcap
-    s = jnp.where(valid_mask[:, None, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgk,bhkd->bhgd", p, v.astype(jnp.float32))
-    return o.reshape(b, hq, 1, d).astype(q.dtype)

@@ -8,7 +8,10 @@ the HLO (and compile time) independent of depth; the group body is wrapped
 in ``jax.checkpoint`` for training (save-residual-boundaries remat policy).
 The serving scans (prefill, chunked prefill, verify, decode) scan over the
 layer index instead and close over the stacks, so the matmul kernel reads
-each layer's projections in place rather than a sliced copy of them.
+each layer's projections in place rather than a sliced copy of them; the
+decode scan also carries the attention caches' stacks, so the decode
+attention kernel reads each layer's cache in place and the new rows are
+written into the stacks.
 
 Three entry points (built per-config by :mod:`repro.models.build`):
   forward(params, batch)          — full-sequence logits (+aux), train/eval
@@ -24,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.distributed.context import constrain, remat_policy
+from repro.distributed.context import constrain, remat_policy, sharded
 from repro.kernels import ops
 from repro.models import attention as attn
 from repro.models import mlp as mlpm
@@ -416,27 +419,48 @@ def verify_step(params: dict, cfg: ArchConfig, cache: dict, tokens: jax.Array,
 
 def decode_step(params: dict, cfg: ArchConfig, cache: dict, tokens: jax.Array, *,
                 provider=None) -> tuple[jax.Array, dict]:
-    """tokens: (B,) — one new token per sequence. Returns (logits (B,V), cache)."""
+    """tokens: (B,) — one new token per sequence. Returns (logits (B,V), cache).
+
+    On one device the layer scan carries the stacked K and V of every
+    attention group of the pattern: each layer writes its B new rows into
+    the stacks at its index and reads its cache in place through
+    :class:`~repro.kernels.ops.LayerRef` (``attn.attn_decode``), so no
+    layer's cache is sliced out or restacked.  Jitted with the cache donated
+    (``ServingEngine``), the step updates it in place: a caller must not
+    read the cache it passed in again.  Recurrent states (``R`` groups) are
+    scanned and restacked, and tail layers take their plain caches, as they
+    are small.  Under a mesh every group's caches are scanned and
+    restacked."""
     pat, reps, tail = _pattern_split(cfg)
     pos = cache["t"]
     h = _embed(params, cfg, tokens[:, None])
+    carried = {} if sharded() else {
+        str(i): cache["groups"][str(i)] for i, kind in enumerate(pat) if kind != "R"}
+    scanned = {i: c for i, c in cache["groups"].items() if i not in carried}
 
     def group_body(carry, xs):
-        hh = carry
+        hh, stacks = carry
         l, layer_cache = xs
         layer_params = _layer_params(params["groups"], l)
-        new_cache = {}
+        new_stacks, new_cache = {}, {}
         for i, kind in enumerate(pat):
-            hh, c_out, _ = apply_block(layer_params[str(i)], cfg, kind, hh,
-                                       positions=None, pos=pos, cache=layer_cache[str(i)],
+            key = str(i)
+            c_in = ({name: ops.LayerRef(s, l) for name, s in stacks[key].items()}
+                    if key in stacks else layer_cache[key])
+            hh, c_out, _ = apply_block(layer_params[key], cfg, kind, hh,
+                                       positions=None, pos=pos, cache=c_in,
                                        decode=True, provider=provider)
-            new_cache[str(i)] = c_out
-        return hh, new_cache
+            if key in stacks:
+                new_stacks[key] = {name: r.stack for name, r in c_out.items()}
+            else:
+                new_cache[key] = c_out
+        return (hh, new_stacks), new_cache
 
     new_cache = {"groups": {}, "tail": [], "t": pos + 1}
     if reps > 0:
-        h, ys = jax.lax.scan(group_body, h, (jnp.arange(reps), cache["groups"]))
-        new_cache["groups"] = ys
+        (h, stacks), ys = jax.lax.scan(group_body, (h, carried),
+                                       (jnp.arange(reps), scanned))
+        new_cache["groups"] = {**ys, **stacks}
     for j, kind in enumerate(tail):
         h, c_out, _ = apply_block(params["tail"][j], cfg, kind, h,
                                   positions=None, pos=pos, cache=cache["tail"][j],

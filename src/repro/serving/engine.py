@@ -69,6 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.resolution import ExecutionPlan, plan_serving
+from repro.kernels import ops
 from repro.models.build import Model
 from repro.obs import ANNOTATION_TRACER, NULL_TRACER
 
@@ -157,6 +158,17 @@ class ServingEngine:
 
         Called at init and after every re-plan: schedules are resolved at
         trace time, so a plan swap must drop stale traces to take effect.
+
+        Where the kernels compile for the chip, the decode step donates its
+        cache: the step writes the new rows into the donated buffers in
+        place (``lm.decode_step``) and returns them as the new cache, so the
+        engine holds one cache, not two, and copies none of it.  The
+        pre-step cache is deleted by the call: :meth:`step` replaces
+        ``self.cache`` with the result, and nothing may keep a reference to
+        the old one.  In interpret mode (no TPU) the cache is not donated:
+        the benchmark's planted "state unchanged" fault
+        (``bench/tests/test_bench_run.py``) hands the pre-step cache back to
+        the engine, which a donated call would have deleted.
         """
         model, provider, max_len = self.model, self.provider, self.max_len
 
@@ -168,7 +180,8 @@ class ServingEngine:
             return model.decode_step(params, cache, toks, provider=provider)
 
         self._prefill = jax.jit(prefill_fn)
-        self._decode = jax.jit(decode_fn)
+        self._decode = jax.jit(
+            decode_fn, donate_argnums=() if ops.interpret_mode() else (1,))
 
     # -- prefill buckets -------------------------------------------------------
     def _pad_len(self, n: int) -> int:

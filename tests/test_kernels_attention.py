@@ -86,3 +86,60 @@ def test_bf16_kernel():
     yr = ref.attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(yr, np.float32),
                                rtol=3e-2, atol=3e-2)
+
+
+# ---------------------------------------------------------------------------
+# Decode attention over a layer-indexed cache stack
+# ---------------------------------------------------------------------------
+
+from repro.kernels import decode_attention as da  # noqa: E402
+
+
+@pytest.mark.parametrize(
+    "group,size,pos,ring,softcap,layers,layer,block_kv,cache_dtype", [
+        # full caches, four KV blocks, slots at 0, the middle and size - 1
+        (3, 32, (0, 5, 31, 17), False, 0.0, 1, 0, 8, jnp.float32),
+        # ring caches (size = window) before the wrap: unwritten slots masked
+        (3, 16, (0, 3, 15, 9), True, 0.0, 1, 0, 8, jnp.float32),
+        # ring caches after the wrap: every slot live
+        (3, 16, (16, 40, 23, 100), True, 0.0, 1, 0, 8, jnp.float32),
+        # the G-layer softcap
+        (3, 32, (2, 30, 31, 11), False, 2.0, 1, 0, 16, jnp.float32),
+        # 8 and 9 query rows per KV head (9: no multiple of 8, full extent)
+        (8, 32, (0, 7, 31, 20), False, 0.0, 1, 0, 8, jnp.float32),
+        (9, 24, (23, 0, 12, 1), False, 0.0, 1, 0, 24, jnp.float32),
+        # a stack of three layers: the traced index must pick layer 1
+        (3, 32, (4, 31, 0, 19), False, 0.0, 3, 1, 8, jnp.float32),
+        (9, 16, (15, 16, 3, 40), True, 0.0, 4, 3, 16, jnp.float32),
+        # bf16 q and cache: exact q.k products, p as a bf16 pair in p.v,
+        # float32 out (one bf16 pass over p would miss by about 2e-3)
+        (3, 32, (0, 9, 31, 26), False, 0.0, 2, 1, 8, jnp.bfloat16),
+    ], ids=["full", "ring-before-wrap", "ring-after-wrap", "softcap",
+            "group8", "group9", "stack-layer1", "ring-stack-layer3",
+            "bf16-cache"])
+def test_decode_attention_matches_masked_einsum(group, size, pos, ring, softcap,
+                                                 layers, layer, block_kv,
+                                                 cache_dtype):
+    """The interpret-mode kernel, reading layer ``layer`` of a (L, B, KV, T,
+    hd) stack in blocks of ``block_kv`` rows, equals the float32 einsum
+    (``ref.masked_decode_attention``) over that layer with the masks the
+    decode step used: ``slot <= pos`` for a full cache, ``slot < min(pos +
+    1, size)`` for a ring."""
+    b, hkv, d = len(pos), 2, 16
+    r = np.random.default_rng(size + group + layers)
+    q = jnp.asarray(r.normal(size=(b, hkv * group, 1, d)) * 2, cache_dtype)
+    k = jnp.asarray(r.normal(size=(layers, b, hkv, size, d)), cache_dtype)
+    v = jnp.asarray(r.normal(size=(layers, b, hkv, size, d)), cache_dtype)
+    p = jnp.asarray(pos, jnp.int32)
+    slots = jnp.arange(size)[None, :]
+    valid = (slots < jnp.minimum(p + 1, size)[:, None] if ring
+             else slots <= p[:, None])
+    want = ref.masked_decode_attention(q.astype(jnp.float32),
+                                       k[layer].astype(jnp.float32),
+                                       v[layer].astype(jnp.float32), valid,
+                                       softcap)
+    got = da.decode_attention(q.reshape(b, hkv, group, d), k, v,
+                              jnp.minimum(p + 1, size), layer=jnp.int32(layer),
+                              softcap=softcap, block_kv=block_kv, interpret=True)
+    np.testing.assert_allclose(got.reshape(want.shape), want,
+                               rtol=2e-5, atol=2e-5)

@@ -192,6 +192,62 @@ def test_decode_step_copies_no_stacked_weight(arch):
                                                   "plain": 1}
 
 
+@pytest.mark.parametrize("arch", ["minitron-4b", "mellum2-12b-a2.5b",
+                                  "recurrentgemma-2b"])
+def test_decode_step_carries_the_kv_cache_in_place(arch, rng):
+    """Through the Pallas kernels (interpret mode) the decode step reads
+    every attention layer's cache in place from the stacks its layer scan
+    carries: the provider counts each group's attention layers in place and
+    none sliced, and no cache stack is sliced or scanned over.  Jitted with
+    the cache donated, six greedy steps give the ref backend's tokens and
+    logits: dense GQA, sliding layers whose ring caches wrap and YaRN full
+    layers, and recurrent layers beside an attention layer."""
+    cfg = reduced(get_arch(arch))
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(3))
+    b, s, steps = 2, 10, 6
+    prompt = jnp.asarray(rng.integers(1, cfg.vocab_size, (b, s)), jnp.int32)
+    pat, reps = cfg.layer_pattern, cfg.n_layers // len(cfg.layer_pattern)
+    attn_layers = reps * sum(kind != "R" for kind in pat)
+    assert attn_layers > 0
+
+    def run(backend, provider):
+        with ops.use_backend(backend):
+            decode = jax.jit(lambda p, c, t: model.decode_step(
+                p, c, t, provider=provider), donate_argnums=(1,))
+            logits, cache = model.prefill(params, {"tokens": prompt},
+                                          max_len=s + steps)
+            out = []
+            for _ in range(steps):
+                logits, cache = decode(params, cache, jnp.argmax(logits, -1))
+                out.append(logits)
+        return out
+
+    provider = ops.ScheduleProvider()
+    got = run("pallas", provider)
+    want = run("ref", None)
+    assert provider.stats()["kv_cache"] == {"in_place": attn_layers, "sliced": 0}
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.argmax(g, -1), np.argmax(w, -1))
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-4)
+
+    cache = model.init_cache(b, s + steps)
+    stacks = {c.shape for c in jax.tree_util.tree_leaves(cache["groups"])
+              if c.ndim == 5}
+    assert stacks
+    with ops.use_backend("pallas"):
+        closed = jax.make_jaxpr(lambda p, c, t: model.decode_step(p, c, t))(
+            params, cache, jnp.zeros((b,), jnp.int32))
+    for eqn in _eqns(closed.jaxpr):
+        if eqn.primitive.name in ("dynamic_slice", "slice", "gather"):
+            operands = eqn.invars[:1]
+        elif eqn.primitive.name == "scan":   # xs follow the consts and carry
+            operands = eqn.invars[eqn.params["num_consts"] + eqn.params["num_carry"]:]
+        else:
+            continue
+        assert not [v for v in operands if getattr(v.aval, "shape", ()) in stacks]
+
+
 # ---------------------------------------------------------------------------
 # Experts on the serving paths: dropless, pads routed nowhere, read in place
 # ---------------------------------------------------------------------------

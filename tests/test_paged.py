@@ -288,6 +288,29 @@ def test_paged_matches_slot_windowed_and_recurrent(arch):
         assert pr.generated == sr.generated
 
 
+@pytest.mark.parametrize("arch", ["mixtral-8x22b", "recurrentgemma-2b"])
+def test_paged_matches_slot_on_pallas_windowed_and_recurrent(arch):
+    """Through the Pallas kernels (interpret mode), where the decode step
+    reads each attention layer's cache in place from the stacks it carries
+    (the paged engine's gathered dense view, the slot engine's cache):
+    ring caches past the window and recurrent layers give the same tokens
+    in the paged engine as in the slot engine."""
+    cfg = reduced(get_arch(arch))
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(1))
+    prompts = _prompts(cfg, lens=(4, 13))  # crosses the reduced window
+    with use_backend("pallas"):
+        paged_reqs, _ = _run_paged(model, params, prompts, page_size=4,
+                                   chunk=6, mnt=4)
+        slot = ServingEngine(model, params, slots=len(prompts), max_len=32,
+                             prefill_buckets=False)
+        slot_reqs = [slot.add_request(p, max_new_tokens=4) for p in prompts]
+        while slot.active:
+            slot.step()
+    for pr, sr in zip(paged_reqs, slot_reqs):
+        assert pr.generated == sr.generated and len(pr.generated) == 4
+
+
 def test_live_defrag_is_bit_exact(small_lm):
     """A defrag forced mid-generation moves live KV pages and changes
     nothing observable: tokens and final-chunk logits match a run that

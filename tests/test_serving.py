@@ -224,3 +224,48 @@ def test_windowed_arch_serving():
     r = eng.add_request(list(np.arange(1, 13)), max_new_tokens=12)
     eng.run_to_completion(max_steps=64)
     assert r.done and len(r.generated) == 12
+
+
+def test_decode_donates_its_cache_where_kernels_compile(small_lm, monkeypatch):
+    """Where the kernels compile for the chip (``ops.interpret_mode`` false;
+    the ref backend here, so nothing compiles for a TPU), each decode step
+    donates the engine's cache: every leaf of the pre-step cache is deleted
+    after the step, the engine serves from the returned cache, and the
+    tokens equal an engine that does not donate (interpret mode)."""
+    from repro.kernels import ops
+
+    cfg, model, params = small_lm
+    prompts = [[5, 6, 7, 8], [9, 10, 11]]
+
+    def serve():
+        eng = ServingEngine(model, params, slots=2, max_len=24)
+        reqs = [eng.add_request(p, max_new_tokens=6) for p in prompts]
+        held = []
+        while eng.active:
+            held.append(jax.tree_util.tree_leaves(eng.cache))
+            eng.step()
+        return [r.generated for r in reqs], held
+
+    kept, kept_held = serve()
+    assert not any(leaf.is_deleted() for leaves in kept_held for leaf in leaves)
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    donated, held = serve()
+    assert donated == kept
+    assert len(held) == 5
+    assert all(leaf.is_deleted() for leaves in held for leaf in leaves)
+
+
+def test_serving_reads_every_attention_cache_in_place(small_lm):
+    """The engine's decode step through the Pallas kernels (interpret mode)
+    reads each attention layer's cache in place from the stack: the
+    provider counts every layer in place and none sliced."""
+    from repro.kernels import ops
+
+    cfg, model, params = small_lm
+    provider = ops.ScheduleProvider()
+    with ops.use_backend("pallas"):
+        eng = ServingEngine(model, params, slots=2, max_len=16, provider=provider)
+        r = eng.add_request([1, 2, 3], max_new_tokens=3)
+        eng.run_to_completion()
+    assert r.done and len(r.generated) == 3
+    assert provider.stats()["kv_cache"] == {"in_place": cfg.n_layers, "sliced": 0}

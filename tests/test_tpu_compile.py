@@ -307,3 +307,80 @@ def test_ragged_expert_gemm_compiles_reading_the_stack_in_place(one_chip, class_
     assert "tpu_custom_call" in text
     assert not re.findall(rf"= bf16\[(?:3,|1,)?64,{k},{n}\]\S* (?:copy|dynamic-slice|fusion)",
                           text)
+
+
+@pytest.mark.parametrize("arch,slots,size,softcap", [
+    ("minitron-4b", 8, 2048, 0.0),          # 3 query rows a KV head
+    ("starcoder2-7b", 16, 4096, 0.0),       # 9: no multiple of 8
+    ("mellum2-12b-a2.5b", 16, 1024, 0.0),   # a ring layer of the window
+    ("gemma2-2b", 8, 2048, GEMMA.attn_softcap),
+])
+def test_decode_attention_compiles_reading_the_cache_stack(one_chip, arch, slots,
+                                                           size, softcap):
+    """The decode-attention kernel at each served configuration's widths,
+    reading layer ``l`` (traced) of a (3, B, KV, T, hd) cache stack in
+    place: it compiles, and no copy or slice of the stack or of a layer's
+    cache is made."""
+    import re
+
+    from repro.kernels import decode_attention as da
+
+    cfg = get_arch(arch)
+    kv, g = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+    layer = (slots, kv, size, cfg.head_dim)
+    text = _compile(
+        lambda q, k, v, lim, l: da.decode_attention(q, k, v, lim, layer=l,
+                                                    softcap=softcap,
+                                                    interpret=False),
+        _sds(one_chip, (slots, kv, g, cfg.head_dim)),
+        _sds(one_chip, (3,) + layer), _sds(one_chip, (3,) + layer),
+        _sds(one_chip, (slots,), jnp.int32), _sds(one_chip, (), jnp.int32)).as_text()
+    assert "tpu_custom_call" in text
+    dims = ",".join(map(str, layer))
+    assert not re.findall(rf"= bf16\[(?:3,|1,)?{dims}\]\S* (?:copy|dynamic-slice|fusion)",
+                          text)
+
+
+def test_engine_decode_donates_the_cache_and_copies_none_of_it(one_chip,
+                                                              monkeypatch):
+    """The slot engine's jitted decode step at minitron-4b's widths (two
+    layers, 8 slots of 2048 positions), compiled for a v5e where the kernels
+    compile: the donated cache is aliased to the new cache whole, the step
+    needs no temporary the size of a layer's cache, no cache stack is
+    copied or a layer's cache sliced out, and the attention is the
+    ``decode_attention`` kernel."""
+    import dataclasses
+    import re
+
+    from repro.kernels import ops
+    from repro.models import build_model
+    from repro.serving import ServingEngine
+
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    cfg = dataclasses.replace(get_arch("minitron-4b"), n_layers=2)
+    model = build_model(cfg)
+    eng = ServingEngine(model, None, slots=1, max_len=8)
+    slots, max_len = 8, 2048
+
+    def shapes(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _sds(one_chip, a.shape, a.dtype), tree)
+
+    params = shapes(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = shapes(jax.eval_shape(lambda: model.init_cache(slots, max_len)))
+    with ops.use_backend("pallas"):
+        compiled = eng._decode.lower(params, cache,
+                                     _sds(one_chip, (slots,), jnp.int32)).compile()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(cache))
+    layer_bytes = slots * cfg.n_kv_heads * max_len * cfg.head_dim * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < layer_bytes
+    text = compiled.as_text()
+    stack = f"2,{slots},{cfg.n_kv_heads},{max_len},{cfg.head_dim}"
+    one = f"{slots},{cfg.n_kv_heads},{max_len},{cfg.head_dim}"
+    assert not re.findall(rf"= bf16\[{stack}\]\S* copy\(", text)
+    assert not re.findall(rf"= bf16\[(?:1,)?{one}\]\S* (?:copy|dynamic-slice|fusion)\(",
+                          text)
+    assert re.search(r"%decode_attention[\w.-]* = [^\n]*tpu_custom_call", text)
