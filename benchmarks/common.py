@@ -27,12 +27,14 @@ from repro.obs import percentile  # noqa: F401  (re-export)
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "results")
 TUNING_DIR = os.path.join(RESULTS_DIR, "tuning")
-DRYRUN_DIR = os.path.join(RESULTS_DIR, "dryrun")
 
 FULL_TRIALS = 1536     # "recommended full budget" analogue (scaled from 20k)
 DP, TP = 16, 16        # production single-pod mesh
 SHAPE = "train_4k"
 SEED = 0
+#: Printed with every table: which clock ``us_per_call`` is on.
+CLOCK_NOTE = ("times are cost-model microseconds or virtual search seconds "
+              "(runner_cache: host wall time of the search), never a device time")
 
 
 def _tuning_path(arch: str, shape: str = SHAPE) -> str:
@@ -102,9 +104,11 @@ def time_to_reach(d: dict, target_seconds: float) -> float | None:
 
 
 def emit(rows: list[tuple], header: str | None = None) -> None:
-    """CSV lines: name,us_per_call,derived (the harness contract)."""
+    """CSV lines: name,us_per_call,derived (the harness contract), under the
+    table's header and the clock its times are on."""
     if header:
         print(f"# {header}")
+    print(f"# {CLOCK_NOTE}")
     for name, us, derived in rows:
         print(f"{name},{us},{derived}")
 

@@ -1,7 +1,12 @@
-"""Benchmark harness: one module per paper table/figure.
+"""Tuning-search accounting: one module per paper table/figure.
+
+Every time these suites print is a *cost-model microsecond* (a kernel's
+modelled runtime) or a *virtual search second* (the simulated measurement
+harness); see DESIGN.md for the two clocks.  None is a device time: the
+on-chip benchmark is ``bench/run.py`` over ``BENCHMARK.json``'s cells.
 
 Prints ``name,us_per_call,derived`` CSV (the harness contract) and writes
-structured JSON under benchmarks/results/ for EXPERIMENTS.md.
+structured JSON under benchmarks/results/.
 """
 from __future__ import annotations
 
@@ -16,24 +21,15 @@ def main() -> None:
 
     enable_compile_cache()
     from benchmarks import (
-        bench_autoscale,
-        bench_fleet,
         bench_full_tuning,
         bench_gemm_transfer,
         bench_headline,
         bench_heuristic,
         bench_kernel_matrix,
-        bench_obs,
-        bench_paged,
         bench_pool,
         bench_resnet,
-        bench_resolution,
-        bench_roofline,
         bench_runner_cache,
         bench_seqlen,
-        bench_service,
-        bench_slo,
-        bench_spec,
         bench_targets,
     )
 
@@ -46,18 +42,8 @@ def main() -> None:
         ("Fig.7 sequence-length transfer", bench_seqlen),
         ("Fig.8 mixed pool", bench_pool),
         ("§4.3 ResNet18 from ResNet50 (paper's own models)", bench_resnet),
-        ("Roofline (dry-run artifacts)", bench_roofline),
         ("MeasureRunner cached/pruned backends", bench_runner_cache),
-        ("Schedule-registry service cold-start stream", bench_service),
         ("§5.3 server-vs-edge multi-target", bench_targets),
-        ("Execution-plan resolution pipeline", bench_resolution),
-        ("Serving fleet: router + demand-driven tuning", bench_fleet),
-        ("Paged continuous batching vs fixed slots", bench_paged),
-        ("Elastic autoscaling fleet vs fixed sizes", bench_autoscale),
-        ("Observability overhead + trace fidelity", bench_obs),
-        ("Speculative draft-then-verify vs plain paged decode", bench_spec),
-        ("Closed-loop observability: SLO burn-down + tuning priority",
-         bench_slo),
     ]
     only = sys.argv[1] if len(sys.argv) > 1 else None
     t0 = time.monotonic()
@@ -67,8 +53,9 @@ def main() -> None:
         print(f"\n# === {title} ===", flush=True)
         t = time.monotonic()
         common.emit(mod.run())
-        print(f"# ({mod.__name__} took {time.monotonic() - t:.1f}s)", flush=True)
-    print(f"\n# total benchmark wall time: {time.monotonic() - t0:.1f}s")
+        print(f"# ({mod.__name__} took {time.monotonic() - t:.1f}s host wall time)",
+              flush=True)
+    print(f"\n# total host wall time: {time.monotonic() - t0:.1f}s")
 
 
 if __name__ == "__main__":

@@ -13,7 +13,7 @@ Public API:
     ResolutionPipeline / ExecutionPlan / plan_model ........ resolution.py
     Target / get_target / resolve_target ................... repro.targets
 """
-from repro.core.autoscheduler import ModelTuneResult, TuneResult, tune_kernel, tune_model, tune_model_into_db
+from repro.core.autoscheduler import ModelTuneResult, TuneResult, tune_kernel, tune_model
 from repro.core.cost_model import (
     CostBreakdown,
     Measurement,
@@ -111,7 +111,6 @@ __all__ = [
     "transfer_tune",
     "tune_kernel",
     "tune_model",
-    "tune_model_into_db",
 ]
 
 

@@ -26,7 +26,7 @@ from typing import Callable, Sequence
 import numpy as np
 
 from repro.core.cost_model import Measurement
-from repro.core.database import Record, ScheduleDB
+from repro.core.database import Record
 from repro.core.legality import axis_units
 from repro.core.runner import MeasureRunner, resolve_runner, telemetry_delta
 from repro.hw.specs import TPU_V5E
@@ -411,11 +411,3 @@ def tune_model(
         runner_telemetry=telemetry_delta(runner.telemetry(), tele_before),
         target=tname,
     )
-
-
-def tune_model_into_db(db: ScheduleDB, uses: Sequence[KernelUse], model_id: str,
-                       **kw) -> ModelTuneResult:
-    res = tune_model(uses, model_id, **kw)
-    for r in res.records:
-        db.add(r)
-    return res

@@ -8,7 +8,7 @@ the DMA reads the layer's blocks straight from the stack, with no sliced copy
 of the layer's cache in between.  A plain per-layer cache ``(B, KV, T, hd)``
 is a stack of one at layer 0.
 
-Semantics are those of :func:`repro.kernels.ref.masked_decode_attention` with
+Semantics are those of :func:`repro.kernels.ref.masked_attention` with
 the mask ``slot < lim[b]``, where the caller passes ``lim = min(pos + 1, T)``:
 the causal mask of a full cache, and the written slots of a ring cache no
 longer than its window.  q and the cache share a dtype.  Scores, the softmax,

@@ -173,22 +173,25 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return o.reshape(b, hq, sq, d).astype(q.dtype)
 
 
-def masked_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                            valid_mask: jax.Array, softcap: float = 0.0) -> jax.Array:
-    """Single-query attention over the whole cache with an explicit (B, size)
-    validity mask (handles causal prefix and ring-buffer semantics).
-    q (B, Hq, 1, hd); k, v (B, KV, size, hd); float32 scores and sums."""
-    b, hq, _, d = q.shape
-    hkv = k.shape[1]
+def masked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     valid: jax.Array, softcap: float = 0.0) -> jax.Array:
+    """Attention with an explicit validity mask, for the masks the flash
+    kernel's causal/window parameters cannot say: a decode cache's (B, 1, T),
+    a ring prefix's shared (C, T), a speculative verify's per-lane (B, C, T).
+    q (B, Hq, C, hd); k, v (B, KV, T, hd); ``valid`` broadcasts to (B, C, T).
+    Scores, softmax and sums are float32."""
+    b, hq, c, d = q.shape
+    hkv, t = k.shape[1], k.shape[2]
     group = hq // hkv
-    qg = q.reshape(b, hkv, group, d).astype(jnp.float32) * d ** -0.5
-    s = jnp.einsum("bhgd,bhkd->bhgk", qg, k.astype(jnp.float32))
+    qg = q.reshape(b, hkv, group, c, d).astype(jnp.float32) * d ** -0.5
+    s = jnp.einsum("bhgqd,bhtd->bhgqt", qg, k.astype(jnp.float32))
     if softcap > 0:
         s = jnp.tanh(s / softcap) * softcap
-    s = jnp.where(valid_mask[:, None, None, :], s, -1e30)
+    valid = jnp.broadcast_to(valid, (b, c, t))
+    s = jnp.where(valid[:, None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgk,bhkd->bhgd", p, v.astype(jnp.float32))
-    return o.reshape(b, hq, 1, d).astype(q.dtype)
+    o = jnp.einsum("bhgqt,bhtd->bhgqd", p, v.astype(jnp.float32))
+    return o.reshape(b, hq, c, d).astype(q.dtype)
 
 
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array,
@@ -197,7 +200,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array,
     ``< min(pos[b] + 1, size)``."""
     size = k.shape[2]
     valid = jnp.arange(size)[None, :] < jnp.minimum(pos + 1, size)[:, None]
-    return masked_decode_attention(q, k, v, valid, softcap)
+    return masked_attention(q, k, v, valid[:, None, :], softcap)
 
 
 # ---------------------------------------------------------------------------

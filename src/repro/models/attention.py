@@ -17,7 +17,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.distributed.context import sharded
-from repro.kernels import ops
+from repro.kernels import ops, ref
 from repro.models.common import apply_norm, apply_rope, dense_init, dtype_of, norm_params
 
 
@@ -191,8 +191,8 @@ def attn_chunk(p: dict, cfg: ArchConfig, x: jax.Array, kind: str, *,
             ok = ok & (kv_pos[None, :] > q_pos[:, None] - window)
         kk = jnp.concatenate([cache["k"].astype(k.dtype), k], axis=2)
         vv = jnp.concatenate([cache["v"].astype(v.dtype), v], axis=2)
-        out = _masked_chunk_attention(q, kk, vv, ok, cfg,
-                                      softcap=cfg.attn_softcap if kind == "G" else 0.0)
+        out = ref.masked_attention(q, kk, vv, ok,
+                                   softcap=cfg.attn_softcap if kind == "G" else 0.0)
         # Write-after-attention: slot (off+i) % size takes position off+i;
         # ascending i means later (newer) positions win on wrap.
         if s >= size:
@@ -212,25 +212,6 @@ def attn_chunk(p: dict, cfg: ArchConfig, x: jax.Array, kind: str, *,
     out = jnp.swapaxes(out, 1, 2).reshape(b, s, cfg.n_heads * cfg.head_dim)
     y = ops.matmul(out, p["wo"], provider=provider)
     return y, new_cache
-
-
-def _masked_chunk_attention(q, k, v, valid_mask, cfg: ArchConfig,
-                            softcap: float = 0.0):
-    """Multi-query attention with an explicit (C, T) validity mask — the
-    chunk analogue of :func:`repro.kernels.ref.masked_decode_attention`
-    (ring semantics need per-position masks the flash kernel's
-    causal/window params can't say)."""
-    b, hq, c, d = q.shape
-    hkv = k.shape[1]
-    group = hq // hkv
-    qg = q.reshape(b, hkv, group, c, d).astype(jnp.float32) * d ** -0.5
-    s = jnp.einsum("bhgqd,bhtd->bhgqt", qg, k.astype(jnp.float32))
-    if softcap > 0:
-        s = jnp.tanh(s / softcap) * softcap
-    s = jnp.where(valid_mask[None, None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgqt,bhtd->bhgqd", p, v.astype(jnp.float32))
-    return o.reshape(b, hq, c, d).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -326,26 +307,8 @@ def attn_verify(p: dict, cfg: ArchConfig, x: jax.Array, kind: str, *,
 
     slots = jnp.arange(size)
     ok = slots[None, None, :] <= positions[:, :, None]      # (B, C, T)
-    out = _masked_verify_attention(q, ck, cv, ok, cfg,
-                                   softcap=cfg.attn_softcap if kind == "G" else 0.0)
+    out = ref.masked_attention(q, ck, cv, ok,
+                               softcap=cfg.attn_softcap if kind == "G" else 0.0)
     out = jnp.swapaxes(out, 1, 2).reshape(b, s, cfg.n_heads * cfg.head_dim)
     y = ops.matmul(out, p["wo"], provider=provider)
     return y, {"k": ck, "v": cv}
-
-
-def _masked_verify_attention(q, k, v, valid_mask, cfg: ArchConfig,
-                             softcap: float = 0.0):
-    """Multi-query attention with a per-lane (B, C, T) validity mask — the
-    verify analogue of :func:`_masked_chunk_attention`, whose (C, T) mask is
-    shared across the batch and cannot express per-lane offsets."""
-    b, hq, c, d = q.shape
-    hkv = k.shape[1]
-    group = hq // hkv
-    qg = q.reshape(b, hkv, group, c, d).astype(jnp.float32) * d ** -0.5
-    s = jnp.einsum("bhgqd,bhtd->bhgqt", qg, k.astype(jnp.float32))
-    if softcap > 0:
-        s = jnp.tanh(s / softcap) * softcap
-    s = jnp.where(valid_mask[:, None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgqt,bhtd->bhgqd", p, v.astype(jnp.float32))
-    return o.reshape(b, hq, c, d).astype(q.dtype)

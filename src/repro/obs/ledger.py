@@ -22,8 +22,7 @@ question directly::
 
 ``realized_fraction`` is the paper's metric: 1.0 means every served kernel
 already runs its best known schedule — a fully-drained fleet must land
-exactly there, and ``bench_slo`` gates that the ledger's numbers for a
-drained fleet match an offline :func:`~repro.core.transfer.transfer_tune`
+exactly there, matching an offline :func:`~repro.core.transfer.transfer_tune`
 run against the same donor registry.  All costs are the cost model's
 *virtual* seconds — the same seconds the virtual clock charges and the
 tuner optimizes, so ledger speedups and serving latency move together by
@@ -162,7 +161,7 @@ class SpeedupLedger:
         ``use_count`` — the exact aggregation :func:`~repro.core.transfer.\
 transfer_tune` reports, so a drained fleet's number is directly comparable
         to the offline ``TransferResult.speedup`` for the same uses and
-        registry (``bench_slo`` gate c)."""
+        registry."""
         un = sv = bt = 0.0
         missing = []
         for u in uses:

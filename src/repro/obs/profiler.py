@@ -15,7 +15,7 @@ Two consumers, one attribution model:
   totals are the *same floats* ``FleetMetrics`` aggregated (the async spans
   carry its exact intervals, and the exporters round-trip seconds
   losslessly), so :func:`critical_path`'s p50/p95 reproduce
-  ``FleetMetrics.summary()`` exactly — pinned by ``bench_slo``.
+  ``FleetMetrics.summary()`` exactly.
 
 * **Live** (:func:`live_workload_seconds`) — the same per-workload
   critical-path seconds computed directly from the replicas' cell

@@ -53,11 +53,11 @@ transfers and syncs as with none.
 
 The slot engine is what the on-chip benchmark (``bench/``) serves.
 :class:`~repro.serving.paged.PagedServingEngine` adds iteration-level
-admission, a paged KV pool and chunked (padding-free) prefill, so far
-measured on the virtual clock only (``benchmarks/bench_paged.py``); the
-slot engine is also the reference semantics for the paged engine's
-equivalence tests and the path for families the paged engine does not
-cover (audio encoder-decoder, vision-prefixed prompts).
+admission, a paged KV pool and chunked (padding-free) prefill; it runs
+on the CPU in tests and is not measured on a chip.  The slot engine is
+also the reference semantics for the paged engine's equivalence tests and
+the path for families the paged engine does not cover (audio
+encoder-decoder, vision-prefixed prompts).
 """
 from __future__ import annotations
 

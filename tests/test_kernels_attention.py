@@ -122,7 +122,7 @@ def test_decode_attention_matches_masked_einsum(group, size, pos, ring, softcap,
                                                  cache_dtype):
     """The interpret-mode kernel, reading layer ``layer`` of a (L, B, KV, T,
     hd) stack in blocks of ``block_kv`` rows, equals the float32 einsum
-    (``ref.masked_decode_attention``) over that layer with the masks the
+    (``ref.masked_attention``) over that layer with the masks the
     decode step used: ``slot <= pos`` for a full cache, ``slot < min(pos +
     1, size)`` for a ring."""
     b, hkv, d = len(pos), 2, 16
@@ -134,12 +134,59 @@ def test_decode_attention_matches_masked_einsum(group, size, pos, ring, softcap,
     slots = jnp.arange(size)[None, :]
     valid = (slots < jnp.minimum(p + 1, size)[:, None] if ring
              else slots <= p[:, None])
-    want = ref.masked_decode_attention(q.astype(jnp.float32),
-                                       k[layer].astype(jnp.float32),
-                                       v[layer].astype(jnp.float32), valid,
-                                       softcap)
+    want = ref.masked_attention(q.astype(jnp.float32),
+                                k[layer].astype(jnp.float32),
+                                v[layer].astype(jnp.float32), valid[:, None, :],
+                                softcap)
     got = da.decode_attention(q.reshape(b, hkv, group, d), k, v,
                               jnp.minimum(p + 1, size), layer=jnp.int32(layer),
                               softcap=softcap, block_kv=block_kv, interpret=True)
     np.testing.assert_allclose(got.reshape(want.shape), want,
                                rtol=2e-5, atol=2e-5)
+
+
+def _decode_mask():
+    """A decode step's (B, 1, T) mask: slot b attends rows <= pos[b]."""
+    pos = np.array([0, 7, 15])
+    return (np.arange(16)[None, :] <= pos[:, None])[:, None, :], 3, 1
+
+
+def _ring_chunk_mask():
+    """A chunk at offset 11 over [ring of 8 ‖ chunk of 4], window 8: the
+    (C, T) mask every lane shares (``models.attention.attn_chunk``)."""
+    off, size, c, window = 11, 8, 4, 8
+    slots = np.arange(size)
+    ring_pos = off - 1 - np.mod(off - 1 - slots, size)
+    kv_pos = np.concatenate([ring_pos, off + np.arange(c)])
+    q_pos = off + np.arange(c)
+    ok = ((kv_pos[None, :] >= 0) & (kv_pos[None, :] <= q_pos[:, None])
+          & (kv_pos[None, :] > q_pos[:, None] - window))
+    return ok, 2, c
+
+
+def _verify_mask():
+    """A verify step's per-lane (B, C, T) mask: lane b's C draft rows sit at
+    off[b] + i and attend rows <= their position."""
+    positions = np.array([2, 9])[:, None] + np.arange(3)
+    return np.arange(16)[None, None, :] <= positions[:, :, None], 2, 3
+
+
+@pytest.mark.parametrize("mask_fn,softcap", [
+    (_decode_mask, 0.0), (_ring_chunk_mask, 20.0), (_verify_mask, 0.0),
+], ids=["decode", "ring-chunk", "verify"])
+def test_masked_attention_matches_naive_on_masked_rows(mask_fn, softcap):
+    """``ref.masked_attention`` equals ``ref.attention``'s naive path run,
+    row by row, over just the key rows that row's mask admits."""
+    valid, b, c = mask_fn()
+    t = valid.shape[-1]
+    q, k, v = _data(b, 4, 2, c, t, 16, seed=t + c)
+    got = np.asarray(ref.masked_attention(q, k, v, jnp.asarray(valid), softcap))
+    full = np.broadcast_to(valid, (b, c, t))
+    for bi in range(b):
+        for i in range(c):
+            rows = np.flatnonzero(full[bi, i])
+            want = ref.attention(q[bi:bi + 1, :, i:i + 1], k[bi:bi + 1, :, rows],
+                                 v[bi:bi + 1, :, rows], causal=False,
+                                 softcap=softcap)
+            np.testing.assert_allclose(got[bi:bi + 1, :, i:i + 1], want,
+                                       rtol=1e-5, atol=1e-5)

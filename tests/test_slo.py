@@ -5,9 +5,7 @@ per-kind badness, :class:`SLOMonitor` multi-window burn-rate math on a fake
 metrics window, :func:`profiler.critical_path` attribution over a hand-built
 trace, :class:`SpeedupLedger` aggregation identities, and
 :class:`TuningAdvisor` ranking (donor-prior headroom, exhaustion skips,
-deterministic order).  The end-to-end closed loop — advisor-fed prefetch
-beating demand-order tuning to SLO compliance on a live fleet — is gated by
-``benchmarks/bench_slo.py``.
+deterministic order).
 """
 import dataclasses
 

@@ -98,19 +98,39 @@ def test_jobs_dedupe_by_workload_key(registry):
     assert svc.stats()["jobs_enqueued"] == 1
 
 
+class HeldRunner(CachedRunner):
+    """Holds the background job's measurements until ``release`` is set; the
+    lookups' own probes, on threads named ``lookup-*``, pass at once."""
+
+    def __init__(self):
+        super().__init__(AnalyticalRunner())
+        self.release = threading.Event()
+
+    def measure_many(self, *args, **kwargs):
+        if not threading.current_thread().name.startswith("lookup-"):
+            assert self.release.wait(timeout=60), "job never released"
+        return super().measure_many(*args, **kwargs)
+
+
 def test_concurrent_misses_one_job(registry):
-    svc = make_service(registry, max_workers=2)
+    # Hold the job until all eight lookups have returned: a job that
+    # published first would turn the last lookups into exact hits, not
+    # deduplicated misses.
+    runner = HeldRunner()
+    svc = make_service(registry, max_workers=2, runner=runner)
     barrier = threading.Barrier(8)
 
     def hit():
         barrier.wait()
         svc.lookup(TARGET)
 
-    threads = [threading.Thread(target=hit) for _ in range(8)]
+    threads = [threading.Thread(target=hit, name=f"lookup-{i}")
+               for i in range(8)]
     for t in threads:
         t.start()
     for t in threads:
         t.join()
+    runner.release.set()
     svc.drain()
     stats = svc.stats()
     assert stats["jobs_enqueued"] == 1
